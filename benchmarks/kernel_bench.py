@@ -469,54 +469,59 @@ def bench_artifact_io() -> List[Row]:
 
 def bench_shard_matrix() -> List[Row]:
     """Mesh-serving throughput matrix: tokens/s per (data, model) mesh
-    shape through ``launch/serve`` (DESIGN.md §7).
+    shape through ``ServeEngine`` (DESIGN.md §7).
 
-    Each cell is a subprocess so it can force its own host device count
-    (jax locks the device count on first init).  On this CPU container
-    the absolute tok/s is an interpret/emulation artifact — the decisive
-    check is that every mesh shape serves the same request batch through
-    the same jitted programs (bit-identical tokens, asserted by
-    tests/test_serve_mesh.py); the relative cell times expose the
-    collective overhead a real multi-chip host would amortize."""
-    import os
-    import re
-    import subprocess
-    import sys
+    Every mesh shape runs in this process, over the devices it already
+    has: a child process could not reach an accelerator this process
+    holds.  Needs 4 devices — on a CPU host run it under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.  Off-TPU the
+    kernels run in interpret mode and the tok/s is an emulation artifact;
+    the decisive check is that every mesh shape emits the same tokens
+    from the same request batch (asserted here and in
+    tests/test_serve_mesh.py)."""
+    import jax
 
-    rows: List[Row] = []
-    failed = []
-    for data, model in ((1, 1), (2, 2), (4, 1), (1, 4)):
-        need = data * model
-        cmd = [sys.executable, "-m", "repro.launch.serve",
-               "--arch", "qwen1.5-0.5b", "--d-model", "128", "--d-ff", "256",
-               "--vocab", "256", "--requests", "4", "--max-new", "6",
-               "--slots", "2", "--s-max", "64", "--sme", "--backend", "v1",
-               "--mesh", f"{data},{model}", "--host-devices", str(need)]
-        env = {**os.environ,
-               "PYTHONPATH": os.environ.get("PYTHONPATH", "src")}
-        env.pop("XLA_FLAGS", None)          # --host-devices sets it
-        t0 = time.perf_counter()
-        r = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                           timeout=900)
-        wall = time.perf_counter() - t0
-        name = f"shard_matrix/mesh_{data}x{model}"
-        if r.returncode != 0:
-            tail = (r.stderr.strip().splitlines() or ["(no stderr)"])[-1]
-            failed.append(f"{data}x{model}: {tail[:200]}")
-            continue
-        m = re.search(r"throughput: ([0-9.]+) tok/s", r.stdout)
-        toks = re.search(r"'tokens': (\d+)", r.stdout)
-        rows.append((name + "/tok_s",
-                     float(m.group(1)) if m else float("nan"),
-                     f"{need} host devices, sme v1 interpret, "
-                     f"{toks.group(1) if toks else '?'} tokens"))
-        rows.append((name + "/wall_s", round(wall, 1),
-                     "subprocess incl. jax init + compile"))
-    if failed:
-        # raise instead of emitting NaN rows so benchmarks/run.py counts
-        # the suite as failed and CI goes red with the real error
+    from repro.configs import ARCHS, scale_down
+    from repro.core.integrate import convert_params_to_sme
+    from repro.launch.mesh import make_mesh
+    from repro.models import build_model
+    from repro.serve import Request, ServeEngine
+
+    if jax.device_count() < 4:
         raise RuntimeError(
-            f"{len(failed)} shard-matrix cells failed: " + "; ".join(failed))
+            f"shard matrix needs 4 devices, found {jax.device_count()}; on "
+            "CPU set XLA_FLAGS=--xla_force_host_platform_device_count=4 "
+            "before starting the process")
+    cfg = scale_down(ARCHS["qwen1.5-0.5b"], d_model=128, d_ff=256, vocab=256)
+    api = build_model(cfg)
+    params = convert_params_to_sme(
+        jax.tree.map(np.asarray, api.init_params(jax.random.key(0))),
+        squeeze=1, backend="v1")
+    dev = jax.devices()[0]
+    rows: List[Row] = []
+    ref = None
+    for data, model in ((1, 1), (2, 2), (4, 1), (1, 4)):
+        rng = np.random.default_rng(0)
+        reqs = [Request(rid=i, prompt=rng.integers(
+                    0, cfg.vocab, size=5 + i % 4, dtype=np.int32),
+                        max_new_tokens=6) for i in range(4)]
+        t0 = time.perf_counter()
+        eng = ServeEngine(api, params, slots=2, s_max=64, backend="v1",
+                          mesh=make_mesh((data, model)))
+        stats = eng.run(reqs, max_steps=500)
+        wall = time.perf_counter() - t0
+        toks = [r.out_tokens for r in reqs]
+        if ref is None:
+            ref = toks
+        elif toks != ref:
+            raise RuntimeError(
+                f"mesh {data}x{model} emitted different tokens than 1x1")
+        name = f"shard_matrix/mesh_{data}x{model}"
+        rows.append((name + "/tok_s", round(stats["tokens"] / wall, 2),
+                     f"{data * model} {dev.platform} devices, sme v1, "
+                     f"{stats['tokens']} tokens, incl. compile"))
+        rows.append((name + "/wall_s", round(wall, 1),
+                     "engine build + compile + serve"))
     return rows
 
 

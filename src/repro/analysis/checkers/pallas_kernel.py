@@ -14,7 +14,7 @@
     Mosaic lowering time, with a far worse error.
   * PLK003 — ``interpret=`` passed to ``pl.pallas_call`` as a literal
     constant: interpret mode must be plumbed from the caller (the
-    off-TPU default lives in ``core.backend._default_interpret``), never
+    off-TPU default lives in ``core.backend._resolve_interpret``), never
     baked into a kernel.
 """
 from __future__ import annotations
@@ -219,5 +219,5 @@ class PallasKernelChecker(Checker):
                         node, "PLK003",
                         "interpret= hardcoded in pallas_call — plumb it "
                         "from the caller (off-TPU default: "
-                        "core.backend._default_interpret)"))
+                        "core.backend._resolve_interpret)"))
         return findings

@@ -31,7 +31,9 @@ travel inside the param dict under ``sme_<name>_*`` keys; when absent and
 the arrays are concrete, ``sme_apply`` packs once and memoizes per weight
 (a weakref-validated identity cache), so eager callers also pay packing
 exactly once.  Under tracing with no operands present, kernel backends
-fall back to ``xla`` — packing needs concrete codes.
+fall back to ``xla`` off-TPU — packing needs concrete codes — and raise
+on a TPU, where the kernels must run (as must their compiled form:
+interpret mode is refused there).
 
 Static-shape discipline: the Pallas kernels take no value-dependent static
 arguments.  ``n_bits`` (v1) and ``squeezed`` (v2) are folded into the
@@ -75,8 +77,18 @@ def _is_concrete(x) -> bool:
     return not isinstance(x, jax.core.Tracer)
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Pallas interpret mode for one dispatch: off on a TPU, on anywhere
+    else (the CPU test path) unless the caller pins it.  Interpret mode
+    is refused on a TPU — it would run the kernels as slow host-emulated
+    XLA programs and hide whether the compiled kernels work."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode is not allowed on a TPU: "
+                         "the SME kernels must run compiled there")
+    return bool(interpret)
 
 
 def _meta_int(param: dict, key: str) -> int:
@@ -638,6 +650,40 @@ class XLABackend(SMEBackend):
     # sme_dequant_jnp directly (handles stacked lead dims in one matmul)
 
 
+def _kernel_call(kernel, x, operands, col_axes):
+    """``kernel(x, *operands) -> y [M, Nt * bn]`` — one Pallas kernel
+    call, made partitionable.  GSPMD cannot split a Mosaic kernel, so
+    under a serving policy on a multi-device mesh the call runs inside a
+    ``shard_map``: ``x`` replicated, each operand split along its
+    column-tile axis (``col_axes[i]``; ``None`` = replicated) over
+    'model' when the tile count divides, and ``y`` sharded by output
+    column tiles — the layout ``parallel.sharding`` gives the operands.
+    Every output column is still computed whole on one device, so the
+    result equals the single-device call."""
+    from jax.sharding import PartitionSpec as P
+    from repro.parallel.policy import current_policy
+    pol = current_policy()
+    mesh = getattr(pol, "mesh", None)
+    if mesh is None or mesh.size == 1:
+        return kernel(x, *operands)
+    msz = mesh.shape.get("model", 1)
+    nt = next(op.shape[ax] for op, ax in zip(operands, col_axes)
+              if ax is not None)
+    split = msz > 1 and nt % msz == 0
+
+    def spec(op, ax):
+        if not split or ax is None:
+            return P()
+        return P(*([None] * ax + ["model"] + [None] * (op.ndim - ax - 1)))
+
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(P(),) + tuple(spec(op, ax)
+                                for op, ax in zip(operands, col_axes)),
+        out_specs=P(None, "model") if split else P(),
+        check_vma=False)(x, *operands)
+
+
 @functools.partial(jax.jit, static_argnames=("n", "bm", "interpret"))
 def _v1_call(x2d, codes, sign, rowscale, rowid, nnz, scale, qscale,
              *, n, bm, interpret):
@@ -649,8 +695,10 @@ def _v1_call(x2d, codes, sign, rowscale, rowid, nnz, scale, qscale,
     xp = jnp.zeros((mp, nr * bk), x2d.dtype).at[:m, :k].set(x2d)
     # n_bits folded into qscale (= 2^-n_bits, exact), so the kernel needs
     # no value-dependent static argument and meta can stay traced
-    y = sme_spmm(xp, codes, sign, rowscale, rowid, nnz,
-                 n_bits=0, bm=bm, out_dtype=jnp.float32, interpret=interpret)
+    y = _kernel_call(
+        functools.partial(sme_spmm, n_bits=0, bm=bm, out_dtype=jnp.float32,
+                          interpret=interpret),
+        xp, (codes, sign, rowscale, rowid, nnz), (0, 0, 0, 0, 0))
     return y[:m, :n] * scale * qscale
 
 
@@ -667,29 +715,29 @@ class SpmmV1Backend(SMEBackend):
     def matmul2d(self, x2d, ops, param, *, bm=128, interpret=None,
                  plane_depth=None):
         del plane_depth               # no per-plane payload: draft == exact
-        if interpret is None:
-            interpret = _default_interpret()
+        interpret = _resolve_interpret(interpret)
         n = _param_kn(param)[1]
         scale = param["sme_scale"].reshape(1, -1).astype(jnp.float32)
         nbits = jnp.asarray(param.get("sme_nbits", 8), jnp.float32)
         return _v1_call(x2d, ops["codes"], ops["sign"], ops["rowscale"],
                         ops["rowid"], ops["nnz"], scale, jnp.exp2(-nbits),
-                        n=n, bm=bm, interpret=bool(interpret))
+                        n=n, bm=bm, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "bn", "bm", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n", "bm", "interpret"))
 def _v2_call(x2d, packed, rowscale, rowid, nnz, scale, qscale,
-             *, n, bn, bm, interpret):
+             *, n, bm, interpret):
     from repro.kernels.sme_spmm.sme_spmm6 import sme_spmm6
     m, k = x2d.shape
-    bk = packed.shape[-2]
+    bk = packed.shape[-2] * 4 // 3
     nr = -(-k // bk)
     mp = -(-m // bm) * bm
     xp = jnp.zeros((mp, nr * bk), x2d.dtype).at[:m, :k].set(x2d)
     # squeezed folded into qscale (= 2^-squeezed, exact): see _v1_call
-    y = sme_spmm6(xp, packed, rowscale, rowid, nnz,
-                  squeezed=0, bn=bn, bm=bm, out_dtype=jnp.float32,
-                  interpret=interpret)
+    y = _kernel_call(
+        functools.partial(sme_spmm6, squeezed=0, bm=bm,
+                          out_dtype=jnp.float32, interpret=interpret),
+        xp, (packed, rowscale, rowid, nnz), (0, 0, 0, 0))
     return y[:m, :n] * scale * qscale
 
 
@@ -726,7 +774,7 @@ class SpmmV2Backend(SMEBackend):
         if int(nnz.max()) > L:
             raise ValueError(
                 f"pad_to={L} < max nnz per column {int(nnz.max())}")
-        packed = np.zeros((nc, L, tr, 3 * tc // 4), np.uint8)
+        packed = np.zeros((nc, L, 3 * tr // 4, tc), np.uint8)
         rowscale = np.ones((nc, L, tr), dtype=np.float32)
         rowid = np.zeros((nc, L), dtype=np.int32)
         col, row, slot = csc_tile_order(occ)
@@ -744,15 +792,13 @@ class SpmmV2Backend(SMEBackend):
     def matmul2d(self, x2d, ops, param, *, bm=128, interpret=None,
                  plane_depth=None):
         del plane_depth               # no per-plane payload: draft == exact
-        if interpret is None:
-            interpret = _default_interpret()
+        interpret = _resolve_interpret(interpret)
         n = _param_kn(param)[1]
-        bn = ops["packed"].shape[-1] * 4 // 3
         scale = param["sme_scale"].reshape(1, -1).astype(jnp.float32)
         sq = jnp.asarray(param.get("sme_squeezed", 1), jnp.float32)
         return _v2_call(x2d, ops["packed"], ops["rowscale"], ops["rowid"],
                         ops["nnz"], scale, jnp.exp2(-sq),
-                        n=n, bn=bn, bm=bm, interpret=bool(interpret))
+                        n=n, bm=bm, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "bm", "interpret"))
@@ -767,9 +813,11 @@ def _v3_call(x2d, planes, sign, rowscale, rowid, shift, last, nnz,
     # the spliced weight is the raw integer codeword (plane bit values
     # 2^shift); 2^-n_bits folds into qscale exactly as in _v1_call, so the
     # epilogue is bit-identical to v1's and meta can stay traced
-    y = sme_spmm_planes(xp, planes, sign, rowscale, rowid, shift, last,
-                        nnz, bm=bm, out_dtype=jnp.float32,
-                        interpret=interpret)
+    y = _kernel_call(
+        functools.partial(sme_spmm_planes, bm=bm, out_dtype=jnp.float32,
+                          interpret=interpret),
+        xp, (planes, sign, rowscale, rowid, shift, last, nnz),
+        (0, 1, 1, 0, 0, 0, 0))
     return y[:m, :n] * scale * qscale
 
 
@@ -821,10 +869,20 @@ def _v3_decode_impl(x2d, planes, sign, rowscale, rowid, shift, last, nnz,
     # to the matmul path's external (y * scale) * qscale
     colscale = jnp.zeros((nt * bn,), jnp.float32).at[:n].set(
         scale.reshape(-1).astype(jnp.float32) * qscale)
-    y = sme_spmm_planes_decode(xp, planes, sign, rowscale,
-                               colscale.reshape(nt, bn), rowid, shift,
-                               last, nnz, G=G, plane_depth=plane_depth,
-                               out_dtype=jnp.float32, interpret=interpret)
+    ops = (planes, sign, rowscale, colscale.reshape(nt, 1, bn), rowid,
+           shift, last, nnz)
+    axes = (0, 1, 1, 0, 0, 0, 0, 0)
+    if plane_depth is not None:
+        # a traced depth rides as a replicated operand
+        ops += (jnp.asarray(plane_depth, jnp.int32),)
+        axes += (None,)
+
+    def kernel(x, *ops):
+        return sme_spmm_planes_decode(
+            x, *ops[:8], G=G, plane_depth=ops[8] if len(ops) > 8 else None,
+            out_dtype=jnp.float32, interpret=interpret)
+
+    y = _kernel_call(kernel, xp, ops, axes)
     return y[:m, :n]
 
 
@@ -866,8 +924,7 @@ class SpmmV3Backend(SMEBackend):
 
     def matmul2d(self, x2d, ops, param, *, bm=128, interpret=None,
                  plane_depth=None):
-        if interpret is None:
-            interpret = _default_interpret()
+        interpret = _resolve_interpret(interpret)
         n = _param_kn(param)[1]
         scale = param["sme_scale"].reshape(1, -1).astype(jnp.float32)
         nbits = jnp.asarray(param.get("sme_nbits", 8), jnp.float32)
@@ -897,17 +954,17 @@ class SpmmV3Backend(SMEBackend):
                     scale, jnp.exp2(-nbits),
                     jnp.asarray(plane_depth, jnp.int32), n=n,
                     G=_static_group_bound(ops["last"], ops["nnz"]),
-                    interpret=bool(interpret))
+                    interpret=interpret)
             return _v3_decode_call(
                 x2d, ops["planes"], ops["sign"], ops["rowscale"],
                 ops["rowid"], ops["shift"], ops["last"], ops["nnz"],
                 scale, jnp.exp2(-nbits), n=n,
                 G=_static_group_bound(ops["last"], ops["nnz"]),
-                interpret=bool(interpret))
+                interpret=interpret)
         return _v3_call(x2d, ops["planes"], ops["sign"], ops["rowscale"],
                         ops["rowid"], ops["shift"], ops["last"], ops["nnz"],
                         scale, jnp.exp2(-nbits),
-                        n=n, bm=bm, interpret=bool(interpret))
+                        n=n, bm=bm, interpret=interpret)
 
 
 # ------------------------------------------------------------------ dispatch
@@ -965,6 +1022,12 @@ def sme_apply(x: jax.Array, param: dict, backend: Optional[str] = None,
             ops = be.operands_from_param(param)
         elif _is_concrete(param["sme_codes"]):
             ops = _cached_operands(param, be, bm, pd)
+        elif jax.default_backend() == "tpu":
+            raise ValueError(
+                f"SME backend {be.name!r} has no packed operands for a "
+                "traced weight; on a TPU the kernels must run, so pack "
+                "them first (convert_params_to_sme(..., backend=...) or "
+                "ensure_operands) instead of falling back to xla")
         else:
             be = get_backend("xla")   # traced raw codes: cannot pack here
             pd = None

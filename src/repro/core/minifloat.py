@@ -65,33 +65,41 @@ def decode6_value(code6: np.ndarray, n_bits: int = 8,
 
 
 def pack6(code6: np.ndarray) -> np.ndarray:
-    """[..., N] uint8 6-bit codes -> [..., 3N/4] bytes (N % 4 == 0)."""
-    assert code6.shape[-1] % 4 == 0
-    g = code6.reshape(code6.shape[:-1] + (-1, 4)).astype(np.uint16)
-    b0 = (g[..., 0] | (g[..., 1] << 6)) & 0xFF
-    b1 = ((g[..., 1] >> 2) | (g[..., 2] << 4)) & 0xFF
-    b2 = ((g[..., 2] >> 4) | (g[..., 3] << 2)) & 0xFF
-    return np.stack([b0, b1, b2], axis=-1).reshape(
-        code6.shape[:-1] + (-1,)).astype(np.uint8)
+    """[..., R, C] uint8 6-bit codes -> [..., 3R/4, C] bytes (R % 4 == 0).
+
+    Row-blocked: the four row quarters ``c0..c3`` of a tile share one
+    byte triple per column (``c0 | c1 << 6``, ``c1 >> 2 | c2 << 4``,
+    ``c2 >> 4 | c3 << 2``) — so the v2 kernel unpacks with aligned
+    sublane slices and no lane shuffles (the TPU lowering has no
+    lane-splitting reshape)."""
+    r = code6.shape[-2]
+    if r % 4:
+        raise ValueError(f"pack6 needs a row count divisible by 4, got {r}")
+    g = code6.astype(np.uint16).reshape(
+        code6.shape[:-2] + (4, r // 4) + code6.shape[-1:])
+    c0, c1, c2, c3 = (g[..., t, :, :] for t in range(4))
+    b0 = (c0 | (c1 << 6)) & 0xFF
+    b1 = ((c1 >> 2) | (c2 << 4)) & 0xFF
+    b2 = ((c2 >> 4) | (c3 << 2)) & 0xFF
+    return np.concatenate([b0, b1, b2], axis=-2).astype(np.uint8)
 
 
 def unpack6(packed: np.ndarray) -> np.ndarray:
-    """Inverse of :func:`pack6` (numpy reference)."""
-    assert packed.shape[-1] % 3 == 0
-    t = packed.reshape(packed.shape[:-1] + (-1, 3)).astype(np.uint16)
-    b0, b1, b2 = t[..., 0], t[..., 1], t[..., 2]
+    """Inverse of :func:`pack6` (numpy reference for the kernel)."""
+    q = packed.shape[-2] // 3
+    t = packed.astype(np.uint16)
+    b0, b1, b2 = (t[..., i * q:(i + 1) * q, :] for i in range(3))
     c0 = b0 & 63
     c1 = ((b0 >> 6) | (b1 << 2)) & 63
     c2 = ((b1 >> 4) | (b2 << 4)) & 63
     c3 = (b2 >> 2) & 63
-    return np.stack([c0, c1, c2, c3], axis=-1).reshape(
-        packed.shape[:-1] + (-1,)).astype(np.uint8)
+    return np.concatenate([c0, c1, c2, c3], axis=-2).astype(np.uint8)
 
 
 def minifloat_from_sme(smew: SMEWeight) -> dict:
     """SMEWeight -> packed minifloat-6 arrays (per-tile layout).
 
-    Returns {packed u8 [nr, nc, tr, 3*tc/4], rowscale f32 [nr, nc, tr],
+    Returns {packed u8 [nr, nc, 3*tr/4, tc], rowscale f32 [nr, nc, tr],
     scale f32 [1, N], meta}.
     """
     if smew.live_bits > 7:

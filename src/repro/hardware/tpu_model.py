@@ -13,7 +13,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict
 
-__all__ = ["TPUSpec", "V5E", "roofline_terms", "dominant_term", "model_flops"]
+__all__ = ["TPUSpec", "V5E", "PEAKS", "peak_spec", "roofline_terms",
+           "dominant_term", "model_flops"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +28,20 @@ class TPUSpec:
 
 
 V5E = TPUSpec()
+
+#: per-chip peaks keyed by ``jax.devices()[0].device_kind``.  Source: Google
+#: Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+PEAKS: Dict[str, TPUSpec] = {"TPU v5 lite": V5E}
+
+
+def peak_spec(device_kind: str) -> TPUSpec:
+    """Peaks of the chip JAX reports as ``device_kind``; an unknown kind
+    is an error, never a silent v5e default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table entry for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def roofline_terms(

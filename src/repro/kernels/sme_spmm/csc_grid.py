@@ -12,9 +12,12 @@ holds that skeleton once:
   * :func:`csc_step` — the init / guarded-accumulate / flush kernel body
     scaffolding (``pl.when`` structure);
   * spec builders (:func:`x_spec`, :func:`slot_spec`, :func:`tile_spec`,
-    :func:`out_spec`) — index-map lambdas written against ``*scalars`` so
-    they work for any number of scalar-prefetch arguments, with
-    ``scalars[0]`` always the ``rowid`` array;
+    :func:`column_spec`, :func:`resident_spec`, :func:`out_spec`) —
+    index-map lambdas written against ``*scalars`` so they work for any
+    number of scalar-prefetch arguments, with ``scalars[0]`` always the
+    ``rowid`` array;
+  * :func:`scale_rows` — the ``2^row_exp`` squeeze compensation applied
+    to the input block (one lane vector per list slot);
   * :func:`csc_pallas_call` — grid-spec assembly + ``pl.pallas_call``;
   * :func:`unpack_row_bits` — the row-major bitmap decode shared by the
     v1 sign bitmap and the v3 plane bitmaps (``np.packbits(axis=rows)``
@@ -29,8 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["csc_step", "x_spec", "slot_spec", "tile_spec", "out_spec",
-           "csc_pallas_call", "unpack_row_bits"]
+__all__ = ["csc_step", "x_spec", "slot_spec", "tile_spec", "column_spec",
+           "resident_spec", "out_spec", "csc_pallas_call", "unpack_row_bits",
+           "scale_rows"]
 
 
 def csc_step(nnz_ref, o_ref, acc_ref, accum) -> None:
@@ -82,6 +86,25 @@ def tile_spec(*block: int) -> pl.BlockSpec:
                         (scalars[0][j, l], j) + _p)
 
 
+def column_spec(L: int, *block: int) -> pl.BlockSpec:
+    """Per-column operand [Nt, L, *block]: the whole list of column ``j``
+    in one block.  Used for the f32 ``rowscale`` rows, whose ``(1, bk)``
+    per-slot block the TPU lowering refuses (a second-minor block dim
+    must be a multiple of 8 or the whole axis); the block only changes
+    with ``j``, so it is fetched once per output column."""
+    pad = (0,) * len(block)
+    return pl.BlockSpec((1, L) + tuple(block),
+                        lambda mi, j, l, *scalars, _p=pad: (j, 0) + _p)
+
+
+def resident_spec(shape: Sequence[int]) -> pl.BlockSpec:
+    """Whole-array operand that stays in VMEM for the entire grid (one
+    fetch) — the dense per-tile ``rowscale`` of the plane-CSC kernels,
+    whose ``[nr, nc, bk]`` layout has no legal per-tile block."""
+    zeros = (0,) * len(shape)
+    return pl.BlockSpec(tuple(shape), lambda *_, _z=zeros: _z)
+
+
 def out_spec(bm: int, bn: int) -> pl.BlockSpec:
     return pl.BlockSpec((bm, bn), lambda mi, j, l, *scalars: (mi, j))
 
@@ -123,7 +146,19 @@ def csc_pallas_call(kernel, x: jax.Array, scalars: Sequence[jax.Array],
 
 def unpack_row_bits(packed, bk: int, bn: int):
     """u8 [bk//8, bn] row-packed bitmap (np.packbits along rows, MSB
-    first) -> u8 0/1 bits [bk, bn].  Shared by the v1 sign bitmap and the
-    v3 plane bitmaps."""
-    shifts = 7 - jax.lax.broadcasted_iota(jnp.uint8, (1, 8, 1), 1)
-    return ((packed[:, None, :] >> shifts) & jnp.uint8(1)).reshape(bk, bn)
+    first) -> i32 0/1 bits [bk, bn].  Shared by the v1 sign bitmap and the
+    v3 plane bitmaps.  The bit arithmetic runs on i32: each [8, bn] slab
+    of the expansion is one 32-bit vreg tile, so the final reshape is
+    layout-trivial on the TPU."""
+    p = packed.astype(jnp.int32)
+    shifts = 7 - jax.lax.broadcasted_iota(jnp.int32, (1, 8, 1), 1)
+    return ((p[:, None, :] >> shifts) & 1).reshape(bk, bn)
+
+
+def scale_rows(x, rowscale):
+    """``x [bm, bk] * rowscale [1, bk]`` in f32: the ``2^row_exp`` squeeze
+    compensation of one weight tile's rows, folded into the input block
+    instead of the weight (a lane vector broadcast over sublanes, which
+    the TPU lowers directly).  ``rowscale`` holds exact powers of two, so
+    ``(x * 2^e) @ w == x @ (2^e * w)`` term by term."""
+    return x.astype(jnp.float32) * rowscale

@@ -8,9 +8,9 @@ weight matrix stored as CSC-of-128x128-tiles (see
   * occupied tiles hold uint8 *shifted codewords* (1 byte/weight from HBM
     instead of 2-4 for bf16/f32 — the TPU analogue of the paper's crossbar
     savings, DESIGN.md §2);
-  * dequantization (codes -> f32, sign bits, ``2^row_exp`` squeeze-out
-    compensation) happens **in VMEM on the VPU**, so the MXU sees one dense
-    f32 matmul per tile;
+  * dequantization (codes -> f32, sign bits) happens **in VMEM on the
+    VPU**, with the ``2^row_exp`` squeeze-out compensation applied to the
+    input block, so the MXU sees one dense f32 matmul per tile;
   * empty tiles are never stored; a scalar-prefetch CSC index
     (``rowid``/``nnz``) drives the BlockSpec index maps (megablocks-style)
     so padding slots are skipped with ``pl.when``.
@@ -26,8 +26,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-from .csc_grid import csc_pallas_call, csc_step, slot_spec, unpack_row_bits
+from .csc_grid import csc_pallas_call, csc_step, column_spec, scale_rows, \
+    slot_spec, unpack_row_bits
 
 __all__ = ["sme_spmm"]
 
@@ -36,13 +38,13 @@ def _kernel(rowid_ref, nnz_ref, x_ref, codes_ref, sign_ref, rowscale_ref,
             o_ref, acc_ref, *, n_bits: int, bk: int, bn: int):
     def accum(j, l):
         codes = codes_ref[0, 0]                              # [bk, bn] u8
-        mag = codes.astype(jnp.float32) * (2.0 ** -n_bits)
+        mag = codes.astype(jnp.int32).astype(jnp.float32) * (2.0 ** -n_bits)
         # sign bits packed along rows, MSB-first (np.packbits axis=0)
         bits = unpack_row_bits(sign_ref[0, 0], bk, bn)
         sgn = 1.0 - 2.0 * bits.astype(jnp.float32)
-        rs = rowscale_ref[0, 0]                              # [bk] f32 = 2^row_exp
-        w = mag * sgn * rs[:, None]
-        x = x_ref[...].astype(jnp.float32)
+        w = mag * sgn
+        # [1, bk] f32 = 2^row_exp of this slot's tile rows
+        x = scale_rows(x_ref[...], rowscale_ref[0, pl.ds(l, 1), :])
         acc_ref[...] += jax.lax.dot_general(
             x, w, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -71,6 +73,6 @@ def sme_spmm(
         kernel, x, scalars=(rowid, nnz),
         tensors=(codes, sign, rowscale),
         tensor_specs=[slot_spec(bk, bn), slot_spec(bk // 8, bn),
-                      slot_spec(bk)],
+                      column_spec(L, bk)],
         nt=nt, L=L, bm=bm, bk=bk, bn=bn,
         out_dtype=out_dtype, interpret=interpret)

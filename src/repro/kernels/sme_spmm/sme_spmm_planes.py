@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .csc_grid import csc_pallas_call, csc_step, slot_spec, tile_spec, \
-    unpack_row_bits
+from .csc_grid import csc_pallas_call, csc_step, resident_spec, scale_rows, \
+    slot_spec, tile_spec, unpack_row_bits
 
 __all__ = ["sme_spmm_planes"]
 
@@ -62,9 +62,10 @@ def _kernel(rowid_ref, shift_ref, last_ref, nnz_ref, x_ref, planes_ref,
             # compensation, one MXU matmul for the whole group, reset
             sgn = 1.0 - 2.0 * unpack_row_bits(sign_ref[0, 0], bk, bn
                                               ).astype(jnp.float32)
-            rs = rowscale_ref[0, 0]                    # [bk] = 2^row_exp
-            w = wacc_ref[...] * sgn * rs[:, None]
-            x = x_ref[...].astype(jnp.float32)
+            w = wacc_ref[...] * sgn
+            # [1, bk] = 2^row_exp of tile (rowid, j), VMEM-resident
+            x = scale_rows(x_ref[...],
+                           rowscale_ref[rowid_ref[j, l], pl.ds(j, 1), :])
             acc_ref[...] += jax.lax.dot_general(
                 x, w, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -98,7 +99,7 @@ def sme_spmm_planes(
         kernel, x, scalars=(rowid, shift, last, nnz),
         tensors=(planes, sign, rowscale),
         tensor_specs=[slot_spec(bk // 8, bn), tile_spec(bk // 8, bn),
-                      tile_spec(bk)],
+                      resident_spec(rowscale.shape)],
         nt=nt, L=L, bm=bm, bk=bk, bn=bn,
         out_dtype=out_dtype, interpret=interpret,
         extra_scratch=[pltpu.VMEM((bk, bn), jnp.float32)])

@@ -13,7 +13,8 @@ group's ``last`` slot.
 This variant re-shapes the grid to ``(Nt, G)`` over *tile groups* — all
 planes of one (row, col) tile are spliced inside a single grid step:
 
-  * the plane bitmaps stay in HBM (``pltpu.ANY``) and are streamed by a
+  * the plane bitmaps stay in HBM (``pltpu.MemorySpace.ANY``) and are
+    streamed by a
     manually double-buffered ``make_async_copy`` loop (2-slot VMEM buffer
     + DMA semaphore pair), so splicing plane ``i`` overlaps the fetch of
     plane ``i + 1``;
@@ -55,7 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .csc_grid import unpack_row_bits
+from .csc_grid import resident_spec, scale_rows, unpack_row_bits
 
 __all__ = ["sme_spmm_planes_decode", "plane_group_index"]
 
@@ -138,9 +139,10 @@ def _kernel(g_rowid_ref, g_start_ref, g_count_ref, g_nnz_ref, shift_ref,
 
         sgn = 1.0 - 2.0 * unpack_row_bits(sign_ref[0, 0], bk, bn
                                           ).astype(jnp.float32)
-        rs = rowscale_ref[0, 0]                      # [bk] = 2^row_exp
-        w = wacc_ref[...] * sgn * rs[:, None]
-        x = x_ref[...].astype(jnp.float32)
+        w = wacc_ref[...] * sgn
+        # [1, bk] = 2^row_exp of tile (g_rowid, j), VMEM-resident
+        x = scale_rows(x_ref[...],
+                       rowscale_ref[g_rowid_ref[j, g], pl.ds(j, 1), :])
         acc_ref[...] += jax.lax.dot_general(
             x, w, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -151,7 +153,7 @@ def _kernel(g_rowid_ref, g_start_ref, g_count_ref, g_nnz_ref, shift_ref,
         # fused epilogue: colscale = scale * 2^-n_bits per output column;
         # exact-pow2 scaling commutes with rounding, so this equals the
         # matmul path's caller-side (y * scale) * qscale bitwise
-        o_ref[...] = (acc_ref[...] * colscale_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] * colscale_ref[0]).astype(o_ref.dtype)
 
 
 def sme_spmm_planes_decode(
@@ -159,7 +161,7 @@ def sme_spmm_planes_decode(
     planes: jax.Array,       # u8 [Nt, L, bk//8, bn] bit-packed plane maps
     sign: jax.Array,         # u8 [nr, nc, bk//8, bn] dense packed signs
     rowscale: jax.Array,     # f32 [nr, nc, bk] dense 2^row_exp
-    colscale: jax.Array,     # f32 [Nt, bn] dequant scale * 2^-n_bits
+    colscale: jax.Array,     # f32 [Nt, 1, bn] dequant scale * 2^-n_bits
     rowid: jax.Array,        # i32 [Nt, L]
     shift: jax.Array,        # i32 [Nt, L] plane bit-value exponent
     last: jax.Array,         # i32 [Nt, L] 1 = final plane of its tile group
@@ -204,11 +206,12 @@ def sme_spmm_planes_decode(
         grid=(nt, G),
         in_specs=[
             pl.BlockSpec((m, bk), lambda j, g, *s: (0, s[0][j, g])),
-            pl.BlockSpec(memory_space=pltpu.ANY),        # planes stay in HBM
+            # planes stay in HBM
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec((1, 1, bk // 8, bn),
                          lambda j, g, *s: (s[0][j, g], j, 0, 0)),
-            pl.BlockSpec((1, 1, bk), lambda j, g, *s: (s[0][j, g], j, 0)),
-            pl.BlockSpec((1, bn), lambda j, g, *s: (j, 0)),
+            resident_spec(rowscale.shape),
+            pl.BlockSpec((1, 1, bn), lambda j, g, *s: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((m, bn), lambda j, g, *s: (0, j)),
         scratch_shapes=[

@@ -23,18 +23,27 @@ from repro.models import build_model
 
 
 def add_scale_args(ap: argparse.ArgumentParser) -> None:
-    """Dim overrides shared by compile/serve so artifacts match the model."""
+    """Dim overrides shared by compile/serve so artifacts match the model.
+    Without any of them (and without ``--smoke``) the published config
+    is used at its full widths and depth."""
     ap.add_argument("--d-model", type=int, default=None)
     ap.add_argument("--d-ff", type=int, default=None)
     ap.add_argument("--head-dim", type=int, default=None)
     ap.add_argument("--vocab", type=int, default=None)
+    ap.add_argument("--smoke", action="store_true",
+                    help="scale the config down to its one-layer CPU smoke "
+                         "size (implied by any dim override)")
 
 
 def scaled_config(args):
+    """The published config, or its ``scale_down`` when ``--smoke`` or a
+    dim override asks for it."""
     over = {k: getattr(args, a) for k, a in
             [("d_model", "d_model"), ("d_ff", "d_ff"),
              ("head_dim", "head_dim"), ("vocab", "vocab")]
             if getattr(args, a) is not None}
+    if not over and not args.smoke:
+        return ARCHS[args.arch]
     return scale_down(ARCHS[args.arch], **over)
 
 

@@ -1,7 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = os.environ.get("DRYRUN_XLA_FLAGS",
     "--xla_force_host_platform_device_count=512")
-# ^ MUST precede any jax import: jax locks the device count on first init.
+# the dry-run models a pod on forced host devices: pin the CPU platform so
+# it never claims an attached accelerator (the per-arch children of --all
+# inherit this environment)
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST precede any jax import: jax locks platform and device count
+# on first init.
 
 # Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 # extract the roofline inputs (task §MULTI-POD DRY-RUN / §ROOFLINE).
@@ -35,7 +40,7 @@ from repro.configs import ARCHS, SHAPES, get_config
 from repro.hardware.hlo_analysis import collective_bytes, cost_summary
 from repro.hardware.hlo_costs import analyze_hlo
 from repro.hardware.tpu_model import V5E, model_flops, roofline_terms
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, param_count, active_param_count
 from repro.optim.optim import adamw, cosine_schedule
 from repro.train.loop import make_train_step, pick_microbatches
@@ -257,7 +262,10 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     if skip:
         return {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
                 "status": "skipped", "reason": skip}
-    mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
+    if mesh_kind == "multi":
+        mesh = make_mesh((2, 16, 16), ("pod", "data", "model"))
+    else:
+        mesh = make_mesh((16, 16))
     shape = SHAPES[shape_name]
     cfg, _pad = cell_config(arch, mesh)
     import dataclasses as _dc

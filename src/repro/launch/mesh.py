@@ -1,36 +1,44 @@
-"""Production mesh construction (task §MULTI-POD DRY-RUN + mesh serving).
+"""Device-mesh construction: the one place a ``Mesh`` is built.
 
-``make_production_mesh`` is a FUNCTION so importing this module never
-touches jax device state.  The dry-run launcher sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; ordinary tests/benches see the real (single) device.
+``make_mesh`` is a FUNCTION so importing this module never touches jax
+device state.  Every mesh in the repo (the serving engine's default 1x1
+mesh, ``--mesh data,model`` serving, the multi-pod dry-run, tests) comes
+from it, with ``AxisType.Auto`` axes: shardings are propagated by GSPMD
+from the jit in/out shardings, never typed into avals.  (JAX >= 0.9
+defaults ``jax.make_mesh`` to Explicit axes, under which an unannotated
+gather such as the embedding lookup has no resolvable out-sharding.)
 
-``parse_mesh`` / ``make_serve_mesh`` back the serving launcher's
-``--mesh data,model`` flag: CPU hosts get testable multi-device meshes by
-forcing host platform devices (``--host-devices N``, which the launcher
-must translate into XLA_FLAGS *before* the first jax import — jax locks
-the device count on first init).
+``parse_mesh`` backs the serving launcher's ``--mesh data,model`` flag:
+CPU hosts get testable multi-device meshes by forcing host platform
+devices (``--host-devices N``, which the launcher must translate into
+XLA_FLAGS *before* the first jax import — jax locks the device count on
+first init).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh", "parse_mesh",
-           "make_serve_mesh"]
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+__all__ = ["make_mesh", "parse_mesh"]
 
 
-def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests use subprocesses with
-    --xla_force_host_platform_device_count to get >1)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+def make_mesh(shape: Sequence[int] = (1, 1),
+              axes: Sequence[str] = ("data", "model")):
+    """Mesh of ``shape`` over the first ``prod(shape)`` visible devices,
+    every axis ``AxisType.Auto``.  Raises when too few devices exist."""
+    shape, axes = tuple(int(s) for s in shape), tuple(axes)
+    need = 1
+    for s in shape:
+        need *= s
+    have = jax.device_count()
+    if need > have:
+        raise ValueError(
+            f"mesh {shape} needs {need} devices but only {have} are "
+            f"visible; on CPU pass --host-devices {need} (sets "
+            f"--xla_force_host_platform_device_count before jax init)")
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def parse_mesh(spec: str) -> Tuple[int, int]:
@@ -43,16 +51,3 @@ def parse_mesh(spec: str) -> Tuple[int, int]:
     if data < 1 or model < 1:
         raise ValueError(f"mesh axes must be >= 1, got {spec!r}")
     return data, model
-
-
-def make_serve_mesh(spec: str):
-    """('data,model' string) -> Mesh, validated against visible devices."""
-    data, model = parse_mesh(spec)
-    need = data * model
-    have = jax.device_count()
-    if need > have:
-        raise ValueError(
-            f"mesh {spec} needs {need} devices but only {have} are "
-            f"visible; on CPU pass --host-devices {need} (sets "
-            f"--xla_force_host_platform_device_count before jax init)")
-    return make_local_mesh(data, model)

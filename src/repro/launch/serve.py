@@ -36,13 +36,21 @@ Prometheus text exposition at ``/metrics``.  All of it is host-side:
 tokens are bit-identical with telemetry on, off, or disabled via
 ``SME_TELEMETRY=0``.
 
+Widths: with no dim flag the published config is served at full width
+and depth (on a TPU; see ``chip_smoke.py``).  ``--smoke`` or any dim
+override (``--d-model`` ...) scales it down to one layer for the CPU.
+The compiled programs persist in JAX's compilation cache
+(``launch/cache.py``).
+
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
+        --sme --s-max 512
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --smoke \
         --requests 6 --max-new 12 [--sme] [--squeeze 1] \
         [--metrics-out m.json --trace-out t.jsonl --metrics-port 9090]
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
         --d-model 256 --d-ff 512 --artifact qwen.smez
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
-        --host-devices 8 --mesh 2,2 --sme --backend v1
+        --d-model 128 --host-devices 8 --mesh 2,2 --sme --backend v1
 """
 from __future__ import annotations
 
@@ -173,9 +181,13 @@ def main():
         server, _ = start_metrics_server(args.metrics_port)
         print(f"metrics: http://127.0.0.1:{server.server_port}/metrics")
 
-    from repro.launch.mesh import make_serve_mesh
-    mesh = make_serve_mesh(args.mesh)
-    print(f"mesh: {dict(mesh.shape)} over {jax.device_count()} devices")
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_mesh, parse_mesh
+    print(f"compile cache: {enable_compile_cache()}")
+    mesh = make_mesh(parse_mesh(args.mesh))
+    dev = jax.devices()[0]
+    print(f"mesh: {dict(mesh.shape)} over {jax.device_count()} "
+          f"{dev.platform} devices ({dev.device_kind})")
 
     cfg = scaled_config(args)
     api = build_model(cfg)
@@ -281,7 +293,7 @@ def main():
     for r in reqs[:4]:
         print(f"req {r.rid}: prompt={list(r.prompt)} -> {r.out_tokens}")
     print(f"throughput: {stats['tokens'] / (time.time() - t0):.1f} tok/s "
-          f"(CPU smoke)")
+          f"({dev.platform} smoke incl. compile, not a benchmark)")
 
     if args.metrics_out:
         from repro.obs import write_snapshot

@@ -20,7 +20,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 from jax.sharding import PartitionSpec as P
@@ -46,6 +46,9 @@ class ShardPolicy:
     #   matmul LHS activations are pinned feature-replicated so GSPMD must
     #   all-gather (exact) instead of partial-summing a sharded
     #   contraction (reassociates floats across devices)
+    mesh: Optional[Any] = None          # the Mesh the policy was built for:
+    #   Pallas kernel calls, which GSPMD cannot partition, run under a
+    #   shard_map over it (core.backend._kernel_call)
 
     def batch_axes(self, b: int):
         if self.dp_size > 1 and b % self.dp_size == 0:
@@ -68,7 +71,8 @@ def policy_for(mesh, cfg, kind: str, full_dp: bool = False) -> ShardPolicy:
     if kind in ("train", "prefill") and not heads_tp and not full_dp:
         seq_axis = "model"
     return ShardPolicy(dp=dp, dp_size=dpn, model_size=msz,
-                       heads_tp=heads_tp, seq_axis=seq_axis, full_dp=full_dp)
+                       heads_tp=heads_tp, seq_axis=seq_axis, full_dp=full_dp,
+                       mesh=mesh)
 
 
 @contextlib.contextmanager

@@ -174,8 +174,10 @@ class ServeEngine:
         self.plan = None          # CompilePlan when booted from_artifact
         self.cfg = api.cfg
         self.key = jax.random.key(seed)
-        self.mesh = mesh if mesh is not None else jax.make_mesh(
-            (1, 1), ("data", "model"))
+        if mesh is None:
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((1, 1))
+        self.mesh = mesh
         self.policy = dataclasses.replace(
             policy_for(self.mesh, self.cfg, "decode"), exact=True)
         self._rep = NamedSharding(self.mesh, P())
@@ -511,11 +513,13 @@ class ServeEngine:
         eng.plan = plan
         return eng
 
-    def _scope(self):
+    def scope(self):
         """Trace-time context for the jitted programs: the SME backend
         choice, the block-size override, the engine's ShardPolicy
         (activation constraints + the sme_apply output-feature constraint)
-        and the mesh (so PartitionSpec-based constraints resolve)."""
+        and the mesh (so PartitionSpec-based constraints resolve).  A
+        caller that jits its own function over ``self.params`` traces it
+        under this context to get the engine's numerics and layout."""
         from repro.core.backend import use_backend, use_block
         from repro.parallel.policy import use_policy
         stack = contextlib.ExitStack()
@@ -524,6 +528,29 @@ class ServeEngine:
         stack.enter_context(use_policy(self.policy))
         stack.enter_context(self.mesh)
         return stack
+
+    def lower_programs(self, prefill_batch: int, prefill_len: int,
+                       k: int = 1) -> Dict[str, "jax.stages.Lowered"]:
+        """The engine's ``prefill`` program (``prefill_batch`` prompts
+        padded to ``prefill_len``) and its step program (``k`` scored
+        positions per row; ``k == 1`` is plain decode), lowered under the
+        engine's scope at exactly the shardings a served call uses.
+        ``.compile().as_text()`` then shows what the device runs, e.g.
+        whether the SME kernels are in it (``tpu_custom_call``)."""
+        if not self._ragged_prefill:
+            raise NotImplementedError(
+                "lower_programs covers the ragged decoder-only prefill")
+        sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+        b = self.slots
+        with self.scope():
+            prefill = self._prefill.lower(
+                self.params, {"tokens": sds((prefill_batch, prefill_len), i32)},
+                sds((prefill_batch,), i32))
+            step = self._chunk.lower(
+                self.params, sds((b, k), i32), self.caches, sds((b,), i32),
+                sds((b,), i32), sds((b,), jnp.bool_), sds((b,), jnp.bool_),
+                sds((b,), jnp.float32), self.key)
+        return {"prefill": prefill, "step": step}
 
     # ------------------------------------------------------------ telemetry
     @property
@@ -761,7 +788,7 @@ class ServeEngine:
                 tq = self._t_enq.get(id(r))
                 if tq is not None:
                     self._m["qwait"].observe(t_pf - tq)
-        with self._scope():
+        with self.scope():
             if self._ragged_prefill:
                 logits, pre = self._prefill(self.params, batch,
                                             jnp.asarray(plens))
@@ -841,7 +868,7 @@ class ServeEngine:
             spec_rows = self._spec_rows()
             if spec_rows.any():
                 from repro.core.backend import use_spec_depth
-                with self._scope(), use_spec_depth(self.spec_depth):
+                with self.scope(), use_spec_depth(self.spec_depth):
                     dtoks = np.asarray(self._draft(
                         self.params, jnp.asarray(self.last_token),
                         self.caches, jnp.asarray(self.pos),
@@ -879,7 +906,7 @@ class ServeEngine:
                           for r in self.active], np.float32)
         self.key, sub = jax.random.split(self.key)
         t_call = self.tracer.now() if tr else 0.0
-        with self._scope():
+        with self.scope():
             emitted, live, self.caches = self._chunk(
                 self.params, jnp.asarray(toks), self.caches,
                 jnp.asarray(self.pos), jnp.asarray(quota),
@@ -1141,7 +1168,7 @@ class ServeEngine:
             tq = self._t_enq.get(id(req))
             if tq is not None:
                 self._m["qwait"].observe(t0 - tq)
-        with self._scope():
+        with self.scope():
             self.caches = self._restore(
                 self.caches, self._pool, self._side, jnp.int32(slot),
                 jnp.asarray(ids), jnp.int32(n), jnp.int32(ent.entry_slot))
@@ -1173,7 +1200,7 @@ class ServeEngine:
         ids = np.zeros(self._max_pages, np.int32)
         n = len(plan.entry.page_ids)
         ids[:n] = plan.entry.page_ids
-        with self._scope():
+        with self.scope():
             self._pool, self._side = self._snap(
                 self._pool, self._side, self.caches, jnp.int32(slot),
                 jnp.asarray(ids), jnp.int32(plan.first_new), jnp.int32(n),

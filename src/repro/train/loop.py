@@ -45,9 +45,14 @@ def make_train_step(loss_fn: Callable, optimizer: Optimizer,
         if microbatches == 1:
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         else:
+            # microbatch i takes rows i, i + micro, ...: the batch axis
+            # (data-sharded) stays leading through the reshape, and the
+            # new scan axis is unsharded — scan slices its xs along
+            # axis 0, which must be replicated
             mb = jax.tree.map(
-                lambda x: x.reshape((microbatches, x.shape[0] // microbatches)
-                                    + x.shape[1:]), batch)
+                lambda x: jnp.swapaxes(
+                    x.reshape((x.shape[0] // microbatches, microbatches)
+                              + x.shape[1:]), 0, 1), batch)
             g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 
             def body(carry, b):

@@ -178,7 +178,7 @@ def test_pack_operands6_vectorized_matches_loop():
     nt, L = csc["rowid"].shape
     tr, tc = smew.tile
     signs_t = smew.sign_tiled()
-    packed = np.zeros((nt, L, tr, 3 * tc // 4), np.uint8)
+    packed = np.zeros((nt, L, 3 * tr // 4, tc), np.uint8)
     occ = smew.occupancy
     for j in range(nt):
         rows = np.nonzero(occ[:, j])[0]
@@ -273,3 +273,38 @@ def test_serve_engine_with_kernel_backend():
     stats = eng.run(reqs, max_steps=20)
     assert stats["completed"] == 2
     assert all(len(r.out_tokens) >= 2 for r in reqs)
+
+
+# ------------------------------------------------------- no fallback on TPU
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make the backend layer see a TPU default backend (no chip needed:
+    both guards below refuse before any kernel is built)."""
+    monkeypatch.setattr(B.jax, "default_backend", lambda: "tpu")
+
+
+def test_interpret_mode_refused_on_tpu(on_tpu):
+    assert B._resolve_interpret(None) is False
+    assert B._resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret mode"):
+        B._resolve_interpret(True)
+
+
+def test_interpret_mode_default_off_tpu():
+    assert B._resolve_interpret(None) is True
+    assert B._resolve_interpret(False) is False
+
+
+def test_traced_codes_without_operands_refused_on_tpu(on_tpu):
+    """Under jit the raw codes are traced and cannot be packed: off-TPU
+    sme_apply falls back to xla, on a TPU it must refuse instead."""
+    p = _param(RNG.normal(0, 0.3, (256, 256)))
+    x = jnp.ones((4, 256), jnp.float32)
+    with pytest.raises(ValueError, match="no packed operands"):
+        jax.jit(lambda x, p: B.sme_apply(x, p, "v2")).lower(x, p)
+
+
+def test_auto_resolves_to_a_kernel_on_tpu(on_tpu):
+    assert B.resolve_backend(None, "auto").name == "v2"
+    p = _param(RNG.normal(0, 0.3, (256, 256)))
+    assert B.resolve_backend(p, "auto").name != "xla"

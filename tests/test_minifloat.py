@@ -8,8 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.sme import sme_compress
 from repro.core.minifloat import (
-    encode6, decode6_value, pack6, unpack6, minifloat_from_sme,
-    minifloat_dequant, bits_per_weight6,
+    encode6, decode6_value, pack6, unpack6, minifloat_from_sme, minifloat_dequant, bits_per_weight6,
 )
 from repro.kernels.sme_spmm import sme_linear6_from_weight
 
@@ -19,6 +18,20 @@ RNG = np.random.default_rng(0)
 def test_pack_unpack_roundtrip():
     c = RNG.integers(0, 64, size=(16, 128)).astype(np.uint8)
     assert (unpack6(pack6(c)) == c).all()
+
+
+def test_pack_rows_roundtrip_and_size():
+    """The v2 kernel's row-blocked tile layout: lossless, 0.75 B/code,
+    and the byte triple of column 0 packs its four row quarters."""
+    c = RNG.integers(0, 64, size=(3, 128, 128)).astype(np.uint8)
+    packed = pack6(c)
+    assert packed.shape == (3, 96, 128)
+    assert (unpack6(packed) == c).all()
+    q = c[0, ::32, 0].astype(np.uint16)           # rows 0, 32, 64, 96
+    assert packed[0, 0, 0] == (q[0] | q[1] << 6) & 0xFF
+    assert packed[0, 64, 0] == (q[2] >> 4 | q[3] << 2) & 0xFF
+    with pytest.raises(ValueError, match="divisible by 4"):
+        pack6(c[:, :6])
 
 
 @given(seed=st.integers(0, 200), sq=st.integers(1, 3))

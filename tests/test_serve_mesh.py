@@ -22,7 +22,7 @@ import jax
 import pytest
 
 from repro.configs import ARCHS, get_smoke, scale_down
-from repro.launch.mesh import make_local_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serve import Request, ServeEngine
 
@@ -87,7 +87,7 @@ def test_mesh_tokens_bit_identical(backend, data, model):
     cfg, api, params = _build(backend)
     _, ref = _serve(cfg, api, params, backend, None)
     _, got = _serve(cfg, api, params, backend,
-                    make_local_mesh(data, model))
+                    make_mesh((data, model)))
     assert got == ref, (
         f"mesh ({data},{model}) diverged from 1x1 for backend "
         f"{backend or 'dense'}: {got} != {ref}")
@@ -108,7 +108,7 @@ def test_mesh_tokens_bit_identical_arch_families(arch):
     api = build_model(cfg)
     params = api.init_params(RNG)
     _, ref = _serve(cfg, api, params, None, None)
-    _, got = _serve(cfg, api, params, None, make_local_mesh(2, 2))
+    _, got = _serve(cfg, api, params, None, make_mesh((2, 2)))
     assert got == ref, f"{arch} diverged on 2x2: {got} != {ref}"
 
 
@@ -118,7 +118,7 @@ def test_one_decode_per_step_under_sharding(data, model):
     """PR 3's one-jitted-decode-per-step contract must hold on a mesh."""
     cfg, api, params = _build("v1")
     eng = ServeEngine(api, params, slots=2, s_max=32, backend="v1",
-                      mesh=make_local_mesh(data, model))
+                      mesh=make_mesh((data, model)))
     pending = _requests(cfg)
     steps = 0
     while pending or any(r is not None for r in eng.active):
@@ -138,7 +138,7 @@ def test_default_engine_is_1x1_mesh():
     code path (no unsharded branch left): same tokens, sharded leaves."""
     cfg, api, params = _build(None)
     _, ref = _serve(cfg, api, params, None, None)
-    _, got = _serve(cfg, api, params, None, make_local_mesh(1, 1))
+    _, got = _serve(cfg, api, params, None, make_mesh((1, 1)))
     assert got == ref
     eng = ServeEngine(api, params, slots=2, s_max=32)
     assert dict(eng.mesh.shape) == {"data": 1, "model": 1}
@@ -153,7 +153,7 @@ def test_param_leaves_actually_shard():
         pytest.skip("needs 4 devices")
     cfg, api, params = _build("v1")
     eng = ServeEngine(api, params, slots=2, s_max=32, backend="v1",
-                      mesh=make_local_mesh(2, 2))
+                      mesh=make_mesh((2, 2)))
     sharded = 0
     for path, leaf in jax.tree_util.tree_leaves_with_path(eng.params):
         names = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
@@ -181,7 +181,7 @@ def test_smez_sharded_load_identity(tmp_path, data, model):
     reqs_ref = _requests(cfg)
     ref.run(reqs_ref, max_steps=100)
 
-    mesh = make_local_mesh(data, model)
+    mesh = make_mesh((data, model))
     eng = ServeEngine.from_artifact(api, art, mesh=mesh, slots=2, s_max=32)
     assert eng.backend == "v1"
     # leaves were placed at load: committed jax arrays under the mesh
@@ -204,7 +204,7 @@ def test_hypothesis_ragged_mesh_identity():
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices")
     cfg, api, params = _build(None)
-    mesh = make_local_mesh(2, 2)
+    mesh = make_mesh((2, 2))
 
     @settings(max_examples=5, deadline=None)
     @given(seed=st.integers(0, 2 ** 16),

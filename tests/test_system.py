@@ -82,13 +82,48 @@ def test_serve_engine_completes_requests():
     assert all(len(r.out_tokens) >= 5 for r in reqs)
 
 
+def test_default_mesh_engine_serves_under_jax_defaults():
+    """JAX >= 0.9 gives ``jax.make_mesh`` Explicit axes, under which the
+    embedding gather had no resolvable out-sharding: the engine's default
+    mesh must come from ``launch.mesh.make_mesh`` with Auto axes."""
+    from jax.sharding import AxisType
+    from repro.serve import ServeEngine, Request
+    cfg = get_smoke("qwen1.5-0.5b")
+    api = build_model(cfg)
+    eng = ServeEngine(api, api.init_params(jax.random.key(0)), slots=1,
+                      s_max=16)
+    assert all(t == AxisType.Auto for t in eng.mesh.axis_types)
+    req = Request(rid=0, prompt=np.arange(3, dtype=np.int32),
+                  max_new_tokens=2)
+    assert eng.run([req], max_steps=10)["completed"] == 1
+    assert len(req.out_tokens) == 2
+
+
+def test_engine_lower_programs_compile():
+    """``lower_programs`` lowers the served prefill and step programs at
+    their serving shardings (what the chip smoke inspects for kernels)."""
+    from repro.serve import ServeEngine
+    cfg = scale_down(ARCHS["qwen1.5-0.5b"], d_model=128, d_ff=256)
+    api = build_model(cfg)
+    from repro.core.integrate import convert_params_to_sme
+    params = convert_params_to_sme(
+        jax.tree.map(np.asarray, api.init_params(jax.random.key(0))),
+        backend="v1")
+    eng = ServeEngine(api, params, slots=2, s_max=32, backend="v1")
+    lowered = eng.lower_programs(2, 8)
+    assert set(lowered) == {"prefill", "step"}
+    for low in lowered.values():
+        # off-TPU the kernels run in interpret mode: no Mosaic call
+        assert "tpu_custom_call" not in low.compile().as_text()
+
+
 MULTIDEV_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_smoke
     from repro.models import build_model
-    from repro.launch.mesh import make_local_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel.sharding import param_sharding, batch_sharding
     from repro.parallel.policy import policy_for, use_policy
     from repro.optim import adamw
@@ -96,7 +131,8 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
 
     cfg = get_smoke("qwen1.5-0.5b")
     api = build_model(cfg)
-    mesh = make_local_mesh(2, 4)
+    data, model = (int(a) for a in os.environ["MULTIDEV_MESH"].split(","))
+    mesh = make_mesh((data, model))
     params = api.init_params(jax.random.key(0))
     opt = adamw(1e-3)
     opt_state = opt.init(params)
@@ -119,8 +155,11 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
 """)
 
 
-def test_multidevice_sharded_train_step():
-    env = {**os.environ, "PYTHONPATH": "src"}
+@pytest.mark.parametrize("mesh", ["2,4", "2,1"])
+def test_multidevice_sharded_train_step(mesh):
+    """Microbatched train step on a data-sharded batch: the scan over
+    microbatches must see an unsharded leading axis."""
+    env = {**os.environ, "PYTHONPATH": "src", "MULTIDEV_MESH": mesh}
     r = subprocess.run([sys.executable, "-c", MULTIDEV_SCRIPT],
                        capture_output=True, text=True, env=env,
                        cwd=os.path.dirname(os.path.dirname(__file__)))
