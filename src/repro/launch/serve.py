@@ -30,11 +30,12 @@ per-layer depths the compiler plan stamped into the converted params.
 Telemetry (DESIGN.md §9): ``--metrics-out m.json`` writes the process
 metrics snapshot on exit (TTFT/inter-token histograms, decode-step and
 dispatch counters — ``python -m repro.obs.gate m.json`` is the CI gate),
-``--trace-out t.jsonl`` (or ``t.json`` for Chrome/Perfetto) dumps the
-request-lifecycle trace ring, and ``--metrics-port N`` serves the live
-Prometheus text exposition at ``/metrics``.  All of it is host-side:
-tokens are bit-identical with telemetry on, off, or disabled via
-``SME_TELEMETRY=0``.
+``--profile-dir DIR`` records serving under the JAX profiler (one
+TensorBoard/Perfetto trace holding the engine's ``serve.*`` host spans
+and the device ops, on one clock), and ``--metrics-port N`` serves the
+live Prometheus text exposition at ``/metrics``.  Tokens are
+bit-identical with telemetry on, off, or disabled via
+``SME_TELEMETRY=0``, and with the profiler recording or not.
 
 Widths: with no dim flag the published config is served at full width
 and depth (on a TPU; see ``chip_smoke.py``).  ``--smoke`` or any dim
@@ -46,7 +47,7 @@ The compiled programs persist in JAX's compilation cache
         --sme --s-max 512
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b --smoke \
         --requests 6 --max-new 12 [--sme] [--squeeze 1] \
-        [--metrics-out m.json --trace-out t.jsonl --metrics-port 9090]
+        [--metrics-out m.json --profile-dir prof --metrics-port 9090]
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
         --d-model 256 --d-ff 512 --artifact qwen.smez
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
@@ -152,14 +153,11 @@ def main():
                     help="write the process metrics snapshot (registry "
                          "JSON; DESIGN.md §9) here on exit — CI gates on "
                          "it via `python -m repro.obs.gate`")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write the request-lifecycle trace here on exit: "
-                         "*.jsonl = one span per line (lossless), *.json "
-                         "= Chrome/Perfetto trace_event (load at "
-                         "ui.perfetto.dev)")
-    ap.add_argument("--trace-capacity", type=int, default=4096,
-                    help="trace ring-buffer capacity (oldest spans evict "
-                         "past this)")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record serving under the JAX profiler into DIR "
+                         "(an .xplane.pb under plugins/profile/: serve.* "
+                         "host spans and device ops on one clock; open "
+                         "with TensorBoard or Perfetto; DESIGN.md §9)")
     ap.add_argument("--metrics-port", type=int, default=None,
                     help="serve the Prometheus text exposition on this "
                          "port at /metrics for the process lifetime "
@@ -221,7 +219,6 @@ def main():
         kw = {} if args.backend == "auto" else {"backend": args.backend}
         if args.bm is not None:
             kw["bm"] = args.bm
-        kw["trace_capacity"] = args.trace_capacity
         t0 = time.time()
         eng = ServeEngine.from_artifact(api, args.artifact, mesh=mesh,
                                         slots=args.slots, s_max=args.s_max,
@@ -254,9 +251,7 @@ def main():
             print(f"SME backend: {args.backend}")
         eng = ServeEngine(api, params, slots=args.slots, s_max=args.s_max,
                           backend=args.backend if args.sme else None,
-                          mesh=mesh, bm=args.bm,
-                          trace_capacity=args.trace_capacity,
-                          **spec_kw, **serve_kw)
+                          mesh=mesh, bm=args.bm, **spec_kw, **serve_kw)
 
     rng = np.random.default_rng(0)
     reqs = [Request(rid=i,
@@ -264,6 +259,8 @@ def main():
                                         dtype=np.int32),
                     max_new_tokens=args.max_new)
             for i in range(args.requests)]
+    if args.profile_dir:
+        jax.profiler.start_trace(args.profile_dir)
     t0 = time.time()
     if args.stream:
         # open-stream demo: requests arrive two at a time between engine
@@ -290,6 +287,9 @@ def main():
     else:
         stats = eng.run(reqs, max_steps=500)
         print(f"stats: {stats}")
+    if args.profile_dir:
+        jax.profiler.stop_trace()
+        print(f"profile: {args.profile_dir}")
     for r in reqs[:4]:
         print(f"req {r.rid}: prompt={list(r.prompt)} -> {r.out_tokens}")
     print(f"throughput: {stats['tokens'] / (time.time() - t0):.1f} tok/s "
@@ -299,14 +299,6 @@ def main():
         from repro.obs import write_snapshot
         write_snapshot(args.metrics_out)
         print(f"metrics snapshot: {args.metrics_out}")
-    if args.trace_out:
-        from repro.obs import export_jsonl, export_trace_event
-        if args.trace_out.endswith(".json"):
-            export_trace_event(eng.tracer.buffer, args.trace_out)
-        else:
-            export_jsonl(eng.tracer.buffer, args.trace_out)
-        print(f"trace ({len(eng.tracer.buffer)} spans, "
-              f"{eng.tracer.buffer.dropped} dropped): {args.trace_out}")
 
 
 if __name__ == "__main__":

@@ -159,9 +159,11 @@ def blockwise_attention(
     return out.astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def _decode_attend(q, k, v, kpos, pos, window, scale):
     """Single-step attention. q:[B,1,H,hd]; k/v:[B,W,KV,hd]; kpos:[B?,W];
-    pos:[B] (per-row query position)."""
+    pos:[B] (per-row query position).  Its ops carry the ``attention``
+    scope in the step program's metadata (DESIGN.md §9)."""
     b, _, h, hd = q.shape
     kvh = k.shape[2]
     g = h // kvh
@@ -177,6 +179,7 @@ def _decode_attend(q, k, v, kpos, pos, window, scale):
     return o.reshape(b, 1, h, hd).astype(q.dtype)
 
 
+@jax.named_scope("kv_cache")
 def _masked_row_scatter(cache, new, slot, active):
     """cache:[B,W,...] <- new:[B,...] at per-row ``slot`` [B], only where
     ``active`` [B]; inactive rows keep their cache bytes untouched."""
@@ -379,18 +382,19 @@ def mla_decode(p, x, cache, pos, cfg, active=None):
     pc = _masked_row_scatter(cache["k_pe"], k_pe_t[:, 0], pos, active)
     w_up = p["kv_up"]["w"].reshape(cfg.kv_lora, h, dn + dv)
     w_uk, w_uv = w_up[..., :dn], w_up[..., dn:]
-    q_c = jnp.einsum("bthn,khn->bthk", q_nope.astype(jnp.float32),
-                     w_uk.astype(jnp.float32))
-    s_c = jnp.einsum("bthk,bsk->bhs", q_c, cc.astype(jnp.float32))
-    s_pe = jnp.einsum("bthr,bsr->bhs", q_pe.astype(jnp.float32),
-                      pc.astype(jnp.float32))
-    scale = 1.0 / ((dn + dr) ** 0.5)
-    s = (s_c + s_pe) * scale
-    kpos = jnp.arange(cc.shape[1])[None]
-    s = jnp.where((kpos <= pos[:, None])[:, None, :], s, NEG_INF)
-    prob = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhs,bsk->bhk", prob, cc.astype(jnp.float32))
-    y = jnp.einsum("bhk,khv->bhv", ctx, w_uv.astype(jnp.float32))
+    with jax.named_scope("attention"):
+        q_c = jnp.einsum("bthn,khn->bthk", q_nope.astype(jnp.float32),
+                         w_uk.astype(jnp.float32))
+        s_c = jnp.einsum("bthk,bsk->bhs", q_c, cc.astype(jnp.float32))
+        s_pe = jnp.einsum("bthr,bsr->bhs", q_pe.astype(jnp.float32),
+                          pc.astype(jnp.float32))
+        scale = 1.0 / ((dn + dr) ** 0.5)
+        s = (s_c + s_pe) * scale
+        kpos = jnp.arange(cc.shape[1])[None]
+        s = jnp.where((kpos <= pos[:, None])[:, None, :], s, NEG_INF)
+        prob = jax.nn.softmax(s, axis=-1)
+        ctx = jnp.einsum("bhs,bsk->bhk", prob, cc.astype(jnp.float32))
+        y = jnp.einsum("bhk,khv->bhv", ctx, w_uv.astype(jnp.float32))
     y = linear(y.reshape(b, 1, h * dv).astype(x.dtype), p["o"])
     return y, {"c": cc, "k_pe": pc}
 

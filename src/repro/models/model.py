@@ -79,7 +79,8 @@ def make_decode_chunk(decode_step: Callable) -> Callable:
             logits, c = decode_step(params, tok[:, None], c,
                                     jnp.where(live, ps, 0), live)
             l = logits if logits.ndim == 2 else logits[:, -1]
-            greedy = jnp.argmax(l, axis=-1).astype(jnp.int32)
+            with jax.named_scope("lm_head"):
+                greedy = jnp.argmax(l, axis=-1).astype(jnp.int32)
             cont = live & (i + 1 < nvalid) & (~gat | (greedy == nxt_tok))
             return (cont, c, jnp.where(live, ps + 1, ps)), (l, live)
 

@@ -8,6 +8,9 @@ Compile-time discipline for the multi-pod dry-run:
 * the LM loss never materializes [B, S, V] logits — cross-entropy is
   computed in sequence chunks inside a scan;
 * decode carries all block caches through the same scan.
+
+The decode step names its head for the profiler (``jax.named_scope``,
+DESIGN.md §9): ``lm_head`` on the final norm and head projection.
 """
 from __future__ import annotations
 
@@ -262,6 +265,7 @@ def lm_decode_step(params, token, caches, pos, cfg, active=None):
         return h, new
 
     x, block_caches = jax.lax.scan(body, x, (params["blocks"], caches["blocks"]))
-    x = apply_norm(x, params["final_norm"], cfg.norm)
-    logits = _head_logits(params, cfg, x[:, -1])
+    with jax.named_scope("lm_head"):
+        x = apply_norm(x, params["final_norm"], cfg.norm)
+        logits = _head_logits(params, cfg, x[:, -1])
     return logits, {"first": first_caches, "blocks": block_caches}

@@ -45,7 +45,12 @@ Real-system behaviors covered at small scale:
   over a shared prefix is deterministic, restored state is bit-identical
   to recomputation (DESIGN.md §12);
 * per-request temperature sampling, per-request max_new_tokens and eos,
-  per-token streaming callbacks (``Request.on_token`` / :meth:`poll`).
+  per-token streaming callbacks (``Request.on_token`` / :meth:`poll`);
+* **one clock for tracing** (DESIGN.md §9): the host phases are
+  ``jax.profiler.TraceAnnotation`` spans (``serve.pump``, ``serve.admit``,
+  ``serve.step`` tiled by ``serve.step.plan/dispatch/wait/emit``), so they
+  land in the profiler's trace beside the device ops; the step program's
+  ops carry ``attention`` / ``kv_cache`` / ``lm_head`` named scopes.
 """
 from __future__ import annotations
 
@@ -60,6 +65,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -100,6 +106,23 @@ class Request:
     outcome: Optional[str] = None
 
 
+@dataclasses.dataclass
+class _StepPlan:
+    """One engine step's per-row work and the step program's device
+    arguments after the cache (``pos, quota, gated, active, temps, key``);
+    ``t_planned`` is when the plan was ready (the verify timer's start)."""
+    act: np.ndarray
+    quota: np.ndarray
+    gated: np.ndarray
+    prefilling: np.ndarray
+    spec_rows: np.ndarray
+    dtoks: Optional[np.ndarray]
+    k: int
+    tokens: jax.Array
+    args: tuple
+    t_planned: float
+
+
 def _prompt_bucket(n: int, s_max: int) -> int:
     """Padded prefill length for a max prompt length ``n``: the next power
     of two (>= 8), clamped to the cache ring.  Bucketing keeps the number
@@ -114,7 +137,7 @@ def _prompt_bucket(n: int, s_max: int) -> int:
 class ServeEngine:
     def __init__(self, api, params, *, slots: int = 4, s_max: int = 128,
                  seed: int = 0, backend: Optional[str] = None, mesh=None,
-                 bm: Optional[int] = None, trace_capacity: int = 4096,
+                 bm: Optional[int] = None,
                  spec_len: int = 0, spec_depth=None,
                  chunk_len: Optional[int] = None,
                  page_tokens: Optional[int] = None,
@@ -157,12 +180,12 @@ class ServeEngine:
         ``mesh`` is a jax Mesh with ("data", "model") axes; None builds the
         degenerate 1x1 mesh — there is no unsharded code path.
 
-        ``trace_capacity`` bounds the engine's request-lifecycle trace
-        ring (``self.tracer``, DESIGN.md §9): spans beyond it evict the
-        oldest.  All telemetry is host-side, recorded around the jitted
-        programs — tokens and lowered HLO are identical with it on or
-        off (tested), and ``repro.obs.set_enabled(False)`` reduces the
-        timing/tracing hooks to one branch."""
+        Telemetry (DESIGN.md §9) is host-side, recorded around the jitted
+        programs: registry metrics, and profiler spans that cost one
+        constructor call each while no profiler records.  Tokens are
+        identical with either on or off (tested), and
+        ``repro.obs.set_enabled(False)`` reduces the metric hooks to one
+        branch."""
         from repro.parallel.policy import policy_for
         from repro.parallel.sharding import (cache_sharding, param_sharding,
                                              place_tree)
@@ -270,7 +293,6 @@ class ServeEngine:
                      key):
             logits, live, newc = api.decode_chunk(
                 p, tokens, caches, pos, nvalid, active, gated)
-            keys = jax.random.split(key, tokens.shape[1])
 
             def samp(l, kk):
                 greedy = jnp.argmax(l, axis=-1).astype(jnp.int32)
@@ -279,7 +301,10 @@ class ServeEngine:
                     / jnp.maximum(temps, 1e-6)[:, None], axis=-1)
                 return jnp.where(temps > 0, drawn.astype(jnp.int32), greedy)
 
-            return jax.vmap(samp)(logits, keys), live, newc
+            with jax.named_scope("lm_head"):
+                keys = jax.random.split(key, tokens.shape[1])
+                emitted = jax.vmap(samp)(logits, keys)
+            return emitted, live, newc
 
         self._chunk = jax.jit(
             chunk_fn,
@@ -340,8 +365,8 @@ class ServeEngine:
         # engine's label and double as the engine's stats (the `_stats`
         # property and run()'s returned dict derive from them — one
         # source of truth), so they count unconditionally.  Latency
-        # histograms and trace spans are instrumentation only and check
-        # obs.enabled() at every hook.
+        # histograms are instrumentation only and check obs.enabled() at
+        # every hook; the host spans are profiler annotations.
         self._eid = str(next(_ENGINE_IDS))
         R = obs.get_registry()
         eid = dict(engine=self._eid)
@@ -457,7 +482,6 @@ class ServeEngine:
         self._g_entries = R.gauge(
             "serve_prefix_entries", "live prefix-cache snapshots",
             ("engine",)).labels(**eid)
-        self.tracer = obs.Tracer(capacity=trace_capacity)
         self._t_enq: Dict[int, float] = {}     # id(req) -> enqueue ts
         self._last_tok_t = np.zeros(slots)     # last token ts per slot
 
@@ -574,15 +598,12 @@ class ServeEngine:
                                            outcome=outcome).value)
 
     def _mark_enqueue(self, req: Request) -> None:
-        if obs.enabled() and id(req) not in self._t_enq:
-            self._t_enq[id(req)] = self.tracer.now()
-            self.tracer.event("enqueue", rid=req.rid,
-                              prompt_len=len(req.prompt))
+        # stamped unconditionally: the queue-wait histogram and the
+        # ``serve.admit`` span's argument both read it
+        self._t_enq.setdefault(id(req), time.perf_counter())
 
     def _reject(self, req: Request) -> None:
         self._outcome(req, "rejected")
-        self.tracer.event("reject", rid=req.rid,
-                          prompt_len=len(req.prompt))
         self.events.append({"kind": "reject", "rid": req.rid})
         self._t_enq.pop(id(req), None)
 
@@ -591,7 +612,7 @@ class ServeEngine:
         """One emitted token from the step loop: output list, counters
         (the request's *first* token observes ttft instead of the
         tokens/itl pair, keeping ``itl.count == tokens`` — §9), streaming
-        callback and event, trace event."""
+        callback and event."""
         req.out_tokens.append(tok)
         if not first:
             self._m["tokens"].inc()
@@ -606,14 +627,10 @@ class ServeEngine:
             else:
                 self._m["itl"].observe(t_tok - self._last_tok_t[slot])
             self._last_tok_t[slot] = t_tok
-            self.tracer.event("token", rid=req.rid, slot=int(slot),
-                              pos=int(self.pos[slot]))
 
     def _finish(self, req: Request, slot: int) -> None:
         req.done = True
         self._outcome(req, "completed")
-        self.tracer.event("finish", rid=req.rid,
-                          n_tokens=len(req.out_tokens))
         self.events.append({"kind": "finish", "rid": req.rid,
                             "outcome": "completed"})
         self._t_enq.pop(id(req), None)
@@ -686,26 +703,27 @@ class ServeEngine:
         batched prefill (or prefix restore) per drain window.  Unfittable
         prompts at the queue head are rejected, the rest keep flowing.
         Returns the number of requests admitted."""
-        admitted = 0
-        while self._queue:
-            free = len(self._free_slots())
-            cap = free if self._ragged_prefill else min(1, free)
-            window = []
-            while self._queue and len(window) < cap:
-                req = self._queue[0]
-                try:
-                    self._prefill_len(req)
-                except PromptTooLong:
-                    self._queue.popleft()
-                    self._reject(req)
-                    continue
-                window.append(self._queue.popleft())
-            if not window:
-                break
-            self._admit(window)
-            admitted += len(window)
-        self._g_queue.set(len(self._queue))
-        return admitted
+        with TraceAnnotation("serve.pump"):
+            admitted = 0
+            while self._queue:
+                free = len(self._free_slots())
+                cap = free if self._ragged_prefill else min(1, free)
+                window = []
+                while self._queue and len(window) < cap:
+                    req = self._queue[0]
+                    try:
+                        self._prefill_len(req)
+                    except PromptTooLong:
+                        self._queue.popleft()
+                        self._reject(req)
+                        continue
+                    window.append(self._queue.popleft())
+                if not window:
+                    break
+                self._admit(window)
+                admitted += len(window)
+            self._g_queue.set(len(self._queue))
+            return admitted
 
     def poll(self) -> List[Dict]:
         """Drain and return the pending stream events (token / finish /
@@ -729,12 +747,27 @@ class ServeEngine:
         self._queue.appendleft(req)
         self._m["preemptions"].inc()
         self._g_queue.set(len(self._queue))
-        self.tracer.event("preempt", rid=req.rid, slot=int(slot))
         self.events.append({"kind": "preempt", "rid": req.rid})
         return True
 
     # ------------------------------------------------------------ admission
     def _admit(self, reqs: List[Request]) -> None:
+        """One admission window under a ``serve.admit`` span, whose
+        arguments (computed only while the profiler records) are the
+        window's request count, its padded prompt length and its longest
+        queue wait."""
+        with TraceAnnotation("serve.admit") as span:
+            if not span.is_enabled():
+                self._admit_window(reqs)
+                return
+            t0 = time.perf_counter()
+            wait = max((t0 - self._t_enq[id(r)] for r in reqs
+                        if id(r) in self._t_enq), default=0.0)
+            pad_to = self._admit_window(reqs)
+            span.set_metadata(n_reqs=len(reqs), pad_to=pad_to,
+                              qwait_ms_max=round(wait * 1e3, 3))
+
+    def _admit_window(self, reqs: List[Request]) -> int:
         """One admission window: prefix-cache hits restore their snapshot
         into a free slot; the rest share a single padded prefill call
         over each prompt's one-shot budget (``min(len, chunk_len)``).
@@ -745,7 +778,9 @@ class ServeEngine:
         sample their first token here (and may complete without taking a
         slot); longer prompts keep their slot in the *prefilling* state
         and are chunk-scored by :meth:`step`.  Callers must have
-        validated lengths (``_prefill_len``) and free-slot counts."""
+        validated lengths (``_prefill_len``) and free-slot counts.
+        Returns the padded prompt length (0 when every request hit the
+        prefix cache)."""
         assert reqs and len(reqs) <= len(self._free_slots())
         if self._prefix is not None:
             cold = []
@@ -757,7 +792,7 @@ class ServeEngine:
                     cold.append(r)
             reqs = cold
             if not reqs:
-                return
+                return 0
         plens = np.array([self._prefill_len(r) for r in reqs], np.int32)
         tok_lens = [len(r.prompt) for r in reqs]
         feed = [min(tl, self._c) for tl in tok_lens]
@@ -781,7 +816,7 @@ class ServeEngine:
             batch["frames"] = jnp.zeros(
                 (b, max(max(tok_lens), 2), self.cfg.d_model), jnp.bfloat16)
         tr = obs.enabled()
-        t_pf = self.tracer.now() if tr else 0.0
+        t_pf = time.perf_counter() if tr else 0.0
         if tr:
             # queue wait ends when the admitting prefill starts
             for r in reqs:
@@ -797,19 +832,12 @@ class ServeEngine:
         self._m["prefills"].inc()
         self._m["prefill_reqs"].inc(b)
         if tr:
-            pad_frac = 1.0 - sum(feed) / float(b * pad_to)
-            self._m["pad_frac"].observe(pad_frac)
-            self.tracer.span("prefill", t_pf, n_reqs=b, pad_to=pad_to,
-                             pad_fraction=round(pad_frac, 4),
-                             rids=[r.rid for r in reqs])
+            self._m["pad_frac"].observe(1.0 - sum(feed) / float(b * pad_to))
         temps = np.array([r.temperature for r in reqs], np.float32)
         first = self._sample(logits, temps)
-        t_first = self.tracer.now() if tr else 0.0
+        t_first = time.perf_counter() if tr else 0.0
         for i, req in enumerate(reqs):
             full_fed = feed[i] == tok_lens[i]
-            if tr:
-                self.tracer.event("admit", rid=req.rid, plen=int(plens[i]),
-                                  chunked=not full_fed)
             if full_fed:
                 tok = int(first[i])
                 req.out_tokens.append(tok)
@@ -826,7 +854,6 @@ class ServeEngine:
                         len(req.out_tokens) >= req.max_new_tokens:
                     req.done = True
                     self._outcome(req, "completed")
-                    self.tracer.event("finish", rid=req.rid, n_tokens=1)
                     self.events.append({"kind": "finish", "rid": req.rid,
                                         "outcome": "completed"})
                     self._t_enq.pop(id(req), None)
@@ -841,6 +868,7 @@ class ServeEngine:
             if full_fed:
                 self.last_token[slot, 0] = tok
             self._maybe_snapshot(slot, req)
+        return pad_to
 
     # --------------------------------------------------------------- decode
     def step(self):
@@ -855,12 +883,38 @@ class ServeEngine:
         results are independent of the padded scan length and of what
         the other rows are doing — the bit-identity argument of
         DESIGN.md §12.  Sampling runs in-graph; the cache argument is
-        donated (no per-step double-buffer)."""
+        donated (no per-step double-buffer).
+
+        The step is one ``serve.step`` profiler span, tiled by four
+        children: ``plan`` (host arrays, key split, transfers),
+        ``dispatch`` (the jitted call until it returns), ``wait`` (the
+        read-back, the only place the host blocks on the device) and
+        ``emit`` (bookkeeping and callbacks).  Its counts become span
+        arguments only while the profiler records."""
         act = np.array([r is not None for r in self.active])
         if not act.any():
             return
-        tr = obs.enabled()
-        t_step = self.tracer.now() if tr else 0.0
+        with TraceAnnotation("serve.step") as span:
+            with TraceAnnotation("serve.step.plan"):
+                plan = self._plan_step(act)
+            with TraceAnnotation("serve.step.dispatch"), self.scope():
+                emitted, live, self.caches = self._chunk(
+                    self.params, plan.tokens, self.caches, *plan.args)
+            with TraceAnnotation("serve.step.wait"):
+                emitted = np.asarray(emitted)                  # [K, B]
+                live = np.asarray(live)                        # [K, B]
+            with TraceAnnotation("serve.step.emit"):
+                n_tok = self._emit_step(plan, emitted, live)
+            if span.is_enabled():
+                span.set_metadata(active=int(act.sum()), slots=self.slots,
+                                  chunk=plan.k,
+                                  prefilling=int(plan.prefilling.sum()),
+                                  tokens=n_tok)
+
+    def _plan_step(self, act: np.ndarray) -> "_StepPlan":
+        """The step's per-row work plan, fixed BEFORE any bookkeeping
+        mutates (a speculative draft runs here: its tokens are part of
+        the plan), and the step program's arguments on the device."""
         d = self.spec_len
         spec_rows = np.zeros(self.slots, bool)
         dtoks = None
@@ -875,7 +929,6 @@ class ServeEngine:
                         jnp.asarray(spec_rows)))
                 self._m["spec_rounds"].inc()
                 self._m["spec_draft_tokens"].inc(d * int(spec_rows.sum()))
-        # per-row work plan, fixed BEFORE any bookkeeping mutates
         quota = np.zeros(self.slots, np.int32)
         gated = np.zeros(self.slots, bool)
         prefilling = np.zeros(self.slots, bool)
@@ -905,34 +958,40 @@ class ServeEngine:
         temps = np.array([r.temperature if r is not None else 0.0
                           for r in self.active], np.float32)
         self.key, sub = jax.random.split(self.key)
-        t_call = self.tracer.now() if tr else 0.0
-        with self.scope():
-            emitted, live, self.caches = self._chunk(
-                self.params, jnp.asarray(toks), self.caches,
-                jnp.asarray(self.pos), jnp.asarray(quota),
-                jnp.asarray(gated), jnp.asarray(act),
-                jnp.asarray(temps), sub)
+        args = (jnp.asarray(self.pos), jnp.asarray(quota),
+                jnp.asarray(gated), jnp.asarray(act), jnp.asarray(temps),
+                sub)
+        return _StepPlan(act, quota, gated, prefilling, spec_rows, dtoks, k,
+                         jnp.asarray(toks), args, time.perf_counter())
+
+    def _emit_step(self, plan: "_StepPlan", emitted: np.ndarray,
+                   live: np.ndarray) -> int:
+        """Per-row bookkeeping after a step: emit each live row's tokens
+        (callbacks, counters), retire finished rows, account the
+        speculative rounds.  Returns the number of tokens emitted."""
+        act, quota, gated = plan.act, plan.quota, plan.gated
+        spec_rows, dtoks, d = plan.spec_rows, plan.dtoks, self.spec_len
+        tr = obs.enabled()
         self._m["decode_steps"].inc()
-        emitted = np.asarray(emitted)                          # [K, B]
-        live = np.asarray(live)                                # [K, B]
         if spec_rows.any():
             self._m["spec_verify_steps"].inc(
                 int(live[:, spec_rows].any(axis=1).sum()))
             if tr:
                 self._m["spec_verify_s"].observe(
-                    self.tracer.now() - t_call)
+                    time.perf_counter() - plan.t_planned)
         if tr:
             occ = float(act.mean())
             self._m["occupancy"].observe(occ)
             self._m["padded"].observe(1.0 - occ)
             self._g_pages.set(int(np.sum(
                 -(-self.pos[act] // self.page_tokens))))
-        t_tok = self.tracer.now() if tr else 0.0
+        t_tok = time.perf_counter() if tr else 0.0
+        n_tok = 0
         accepted = np.zeros(self.slots, np.int64)
         for i in np.flatnonzero(act):
             req = self.active[i]
             q = int(quota[i])
-            if prefilling[i]:
+            if plan.prefilling[i]:
                 self._pf_next[i] += q
                 self.pos[i] += q
                 self._maybe_snapshot(i, req)
@@ -941,6 +1000,7 @@ class ServeEngine:
                     # logits — same position the one-shot path samples
                     tok = int(emitted[q - 1, i])
                     self._emit(req, i, tok, t_tok, first=True)
+                    n_tok += 1
                     if (req.eos_id is not None and tok == req.eos_id) or \
                             len(req.out_tokens) >= req.max_new_tokens:
                         self._finish(req, i)
@@ -952,6 +1012,7 @@ class ServeEngine:
                     break
                 tok = int(emitted[v, i])
                 self._emit(req, i, tok, t_tok)
+                n_tok += 1
                 self.pos[i] += 1
                 self.last_token[i, 0] = tok
                 matched = bool(gated[i]) and v < d \
@@ -976,11 +1037,7 @@ class ServeEngine:
             self._m["spec_rolled_back"].inc(d - int(accepted[i]))
             if tr:
                 self._m["spec_accept_frac"].observe(accepted[i] / d)
-        if tr:
-            self.tracer.span("decode_step", t_step,
-                             active=int(act.sum()), slots=self.slots,
-                             chunk=int(k),
-                             prefilling=int(prefilling.sum()))
+        return n_tok
 
     # ------------------------------------------------- speculative decode
     def _spec_rows(self) -> np.ndarray:
@@ -1163,7 +1220,7 @@ class ServeEngine:
         n = len(ent.page_ids)
         ids[:n] = ent.page_ids
         tr = obs.enabled()
-        t0 = self.tracer.now() if tr else 0.0
+        t0 = time.perf_counter() if tr else 0.0
         if tr:
             tq = self._t_enq.get(id(req))
             if tq is not None:
@@ -1175,9 +1232,7 @@ class ServeEngine:
         self.pos[slot] = ent.length
         self._pf_next[slot] = ent.length
         self.active[slot] = req
-        self._last_tok_t[slot] = self.tracer.now() if tr else 0.0
-        self.tracer.event("restore", rid=req.rid, plen=int(ent.length),
-                          pages=n)
+        self._last_tok_t[slot] = time.perf_counter() if tr else 0.0
 
     def _maybe_snapshot(self, slot: int, req: Request) -> None:
         """Snapshot the slot's cache row at a chunk boundary (``pf_next``
@@ -1209,8 +1264,6 @@ class ServeEngine:
         self._m["prefix_snapshots"].inc()
         self._g_pool.set(self._prefix.alloc.in_use)
         self._g_entries.set(len(self._prefix))
-        self.tracer.event("snapshot", rid=req.rid, plen=L,
-                          new_pages=n - plan.first_new)
 
     # ------------------------------------------------------------------ run
     def run(self, requests: List[Request], max_steps: int = 1000) -> Dict:
@@ -1248,8 +1301,6 @@ class ServeEngine:
                 continue
             if r.out_tokens:
                 self._outcome(r, "evicted")
-                self.tracer.event("evict", rid=r.rid,
-                                  n_tokens=len(r.out_tokens))
             else:
                 self._outcome(r, "unserved")
             self._t_enq.pop(id(r), None)

@@ -1,10 +1,15 @@
-"""Telemetry layer (DESIGN.md §9): metrics registry semantics, trace ring
-buffer + exporters, the two invariance properties (telemetry cannot change
-the lowered HLO or the served tokens), the instrumentation hooks in
+"""Telemetry layer (DESIGN.md §9): metrics registry semantics, the
+engine's profiler spans and the step program's named scopes, the two
+invariance properties (telemetry and the profiler cannot change the
+lowered HLO or the served tokens), the instrumentation hooks in
 core/backend + hardware/autotune + ServeEngine, the snapshot CI gate, and
 the Prometheus HTTP endpoint."""
+import glob
 import json
 import logging
+import os
+import subprocess
+import sys
 import urllib.error
 import urllib.request
 
@@ -21,8 +26,6 @@ from repro.obs.gate import check_snapshot, main as gate_main
 from repro.obs.httpd import start_metrics_server
 from repro.obs.metrics import MetricsRegistry, flatten_snapshot, \
     write_snapshot
-from repro.obs.trace import Span, TraceBuffer, Tracer, export_jsonl, \
-    export_trace_event, read_jsonl
 
 RNG = np.random.default_rng(57)
 
@@ -105,62 +108,42 @@ def test_render_text_prometheus_exposition():
     assert "lat_seconds_count 2" in text
 
 
-# ------------------------------------------------------------ trace ring
-def test_trace_ring_is_bounded_and_drops_oldest():
-    buf = TraceBuffer(capacity=8)
-    for i in range(20):
-        buf.add(Span(name=f"s{i}", ts=float(i)))
-        assert len(buf) <= 8
-    assert len(buf) == 8
-    assert buf.dropped == 12
-    assert [s.name for s in buf.spans()] == [f"s{i}" for i in range(12, 20)]
-    buf.clear()
-    assert len(buf) == 0 and buf.dropped == 0
+# ------------------------------------------------------- profiler spans
+STEP_PHASES = ("serve.step.plan", "serve.step.dispatch", "serve.step.wait",
+               "serve.step.emit")
 
 
-def _synthetic_spans():
-    return [Span("enqueue", 0.0, rid=1, attrs={"prompt_len": 5}),
-            Span("prefill", 0.001, dur=0.5, attrs={"n_reqs": 2}),
-            Span("token", 0.7, rid=2)]
+def _serve_events(profile_dir):
+    """``(name, start_ns, end_ns, args)`` of every ``serve.*`` host event
+    in the newest xplane under ``profile_dir``, by start."""
+    files = sorted(glob.glob(os.path.join(str(profile_dir), "**",
+                                          "*.xplane.pb"), recursive=True),
+                   key=os.path.getmtime)
+    assert files, f"no xplane under {profile_dir}"
+    pd = jax.profiler.ProfileData.from_file(files[-1])
+    out = [(ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
+            {k: v for k, v in ev.stats})
+           for plane in pd.planes if plane.name.startswith("/host:")
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("serve.")]
+    return sorted(out, key=lambda e: e[1])
 
 
-def test_trace_jsonl_roundtrip(tmp_path):
-    path = str(tmp_path / "t.jsonl")
-    export_jsonl(_synthetic_spans(), path)
-    back = read_jsonl(path)
-    assert [s.to_dict() for s in back] == \
-        [s.to_dict() for s in _synthetic_spans()]
+def _captured(profile_dir, fn):
+    """``fn()`` run under the JAX profiler (python tracer off), and the
+    ``serve.*`` host events it recorded."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(profile_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    return out, _serve_events(profile_dir)
 
 
-def test_trace_event_export_shape(tmp_path):
-    path = str(tmp_path / "t.json")
-    export_trace_event(_synthetic_spans(), path)
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["displayTimeUnit"] == "ms"
-    by = {e["name"]: e for e in doc["traceEvents"]}
-    assert len(by) == 3
-    # durations are complete events in microseconds on the request track
-    assert by["prefill"]["ph"] == "X"
-    assert by["prefill"]["dur"] == pytest.approx(0.5e6)
-    assert by["prefill"]["tid"] == 0                # engine-level track
-    assert by["enqueue"]["ph"] == "i"
-    assert by["enqueue"]["tid"] == 2                # rid 1 -> track 2
-    assert by["enqueue"]["args"]["rid"] == 1
-    assert by["token"]["ts"] == pytest.approx(0.7e6)
-
-
-def test_tracer_respects_enabled_gate():
-    tr = Tracer(capacity=8)
-    tr.event("enqueue", rid=0)
-    t = tr.now()
-    tr.span("prefill", t, rid=0, n_reqs=1)
-    assert len(tr.buffer) == 2
-    assert tr.buffer.spans()[1].dur >= 0.0
-    obs.set_enabled(False)
-    tr.event("enqueue", rid=1)
-    tr.span("prefill", tr.now(), rid=1)
-    assert len(tr.buffer) == 2                      # nothing recorded
+def _inside(events, name, lo, hi):
+    return [e for e in events if e[0] == name and lo <= e[1] and e[2] <= hi]
 
 
 # --------------------------------------------------- invariance properties
@@ -184,9 +167,10 @@ def test_hlo_invariant_under_telemetry(monkeypatch):
 
 @pytest.mark.parametrize("backend", ["v1", "v2", "v3"])
 def test_serve_tokens_bit_identical_with_tracing(smoke_engine_parts,
-                                                 backend):
-    # greedy tokens must be bit-identical with tracing/metrics enabled vs
-    # fully disabled, through the real slot engine on each kernel backend
+                                                 backend, tmp_path):
+    # greedy tokens must be bit-identical with the profiler recording and
+    # metrics enabled vs no profiler and metrics fully disabled, through
+    # the real slot engine on each kernel backend
     from repro.serve import Request, ServeEngine
     cfg, api, params = smoke_engine_parts
     ps = convert_params_to_sme(params, squeeze=1, backend=backend)
@@ -202,15 +186,16 @@ def test_serve_tokens_bit_identical_with_tracing(smoke_engine_parts,
         stats = eng.run(reqs, max_steps=30)
         return [list(r.out_tokens) for r in reqs], stats, eng
 
-    toks_on, stats_on, eng_on = serve(True)
+    (toks_on, stats_on, eng_on), events = _captured(
+        tmp_path, lambda: serve(True))
     toks_off, stats_off, eng_off = serve(False)
     assert toks_on == toks_off
     assert stats_on["completed"] == stats_off["completed"] == 3
     for k in ("prefills", "prefill_reqs", "decode_steps", "tokens"):
         assert stats_on[k] == stats_off[k], k
-    # tracing captured the run when on, recorded nothing when off
-    assert len(eng_on.tracer.buffer) > 0
-    assert len(eng_off.tracer.buffer) == 0
+    # the profiler holds one serve.step span per decode step
+    steps = [e for e in events if e[0] == "serve.step"]
+    assert len(steps) == stats_on["decode_steps"]
     assert eng_on._m["ttft"].count == 3
     assert eng_off._m["ttft"].count == 0
 
@@ -228,7 +213,7 @@ def smoke_engine_parts():
     return cfg, api, params
 
 
-def test_engine_stats_derive_from_registry(smoke_engine_parts):
+def test_engine_stats_derive_from_registry(smoke_engine_parts, tmp_path):
     from repro.serve import Request, ServeEngine
     cfg, api, params = smoke_engine_parts
     eng = ServeEngine(api, params, slots=2, s_max=32)
@@ -240,7 +225,7 @@ def test_engine_stats_derive_from_registry(smoke_engine_parts):
     # one oversized prompt: rejected in run(), the rest keep serving
     reqs.append(Request(rid=99, prompt=np.zeros(40, np.int32),
                         max_new_tokens=3))
-    stats = eng.run(reqs, max_steps=40)
+    stats, events = _captured(tmp_path, lambda: eng.run(reqs, max_steps=40))
 
     assert set(stats) == {"completed", "evicted", "rejected", "unserved",
                           "wall_s", "prefills", "prefill_reqs",
@@ -269,10 +254,9 @@ def test_engine_stats_derive_from_registry(smoke_engine_parts):
     assert eng._m["pad_frac"].count == stats["prefills"]
     assert eng._m["itl"].count == stats["tokens"]
 
-    # the trace holds the full request lifecycle
-    names = {s.name for s in eng.tracer.buffer.spans()}
-    assert {"enqueue", "admit", "prefill", "token", "finish",
-            "decode_step", "reject"} <= names
+    # the profiler holds every host phase of the engine
+    names = {e[0] for e in events}
+    assert {"serve.pump", "serve.admit", "serve.step", *STEP_PHASES} <= names
 
     # a second run() reports per-run outcome deltas, not lifetime totals,
     # while the stats counters keep accumulating
@@ -284,6 +268,97 @@ def test_engine_stats_derive_from_registry(smoke_engine_parts):
     assert stats2["completed"] == 2
     assert stats2["rejected"] == 0
     assert stats2["decode_steps"] > stats["decode_steps"]
+
+
+def _spans_run(smoke_engine_parts, tmp_path):
+    from repro.serve import Request, ServeEngine
+    cfg, api, params = smoke_engine_parts
+    eng = ServeEngine(api, params, slots=3, s_max=32)
+    reqs = [Request(rid=i, prompt=(np.arange(1, 4 + 2 * i) % cfg.vocab
+                                   ).astype(np.int32),
+                    max_new_tokens=3 + i)
+            for i in range(5)]
+    stats, events = _captured(tmp_path, lambda: eng.run(reqs, max_steps=40))
+    return reqs, stats, events
+
+
+def test_step_phases_tile_the_step_on_one_clock(smoke_engine_parts,
+                                                 tmp_path):
+    # plan / dispatch / wait / emit lie inside their serve.step, in that
+    # order, without overlap, and leave no more than a sliver of it out
+    reqs, stats, events = _spans_run(smoke_engine_parts, tmp_path)
+    steps = [e for e in events if e[0] == "serve.step"]
+    assert len(steps) == stats["decode_steps"] > 0
+    emitted = 0
+    for _, lo, hi, args in steps:
+        kids = [_inside(events, n, lo, hi) for n in STEP_PHASES]
+        assert all(len(k) == 1 for k in kids), kids
+        kids = [k[0] for k in kids]
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+        covered = sum(k[2] - k[1] for k in kids)
+        assert covered <= hi - lo
+        assert hi - lo - covered < max(0.02 * (hi - lo), 200_000)
+        assert args["slots"] == 3 and args["chunk"] >= 1
+        assert 1 <= args["active"] <= 3
+        emitted += args["tokens"]
+    # every emitted token but each request's prefill-sampled first one
+    assert emitted == sum(len(r.out_tokens) - 1 for r in reqs)
+
+
+def test_admit_span_carries_its_window(smoke_engine_parts, tmp_path):
+    reqs, stats, events = _spans_run(smoke_engine_parts, tmp_path)
+    admits = [e for e in events if e[0] == "serve.admit"]
+    assert len(admits) == stats["prefills"]
+    assert sum(a[3]["n_reqs"] for a in admits) == stats["prefill_reqs"] == 5
+    for _, lo, hi, args in admits:
+        assert args["pad_to"] >= 8 and args["qwait_ms_max"] >= 0.0
+        # each admission window runs inside a pump, never inside a step
+        assert [e for e in events if e[0] == "serve.pump"
+                and e[1] <= lo and hi <= e[2]]
+
+
+def test_step_program_holds_the_scopes():
+    # attention, the KV cache's ring-slot writes and the head each carry
+    # their named scope in the compiled step program's metadata
+    from repro.configs import ARCHS, scale_down
+    from repro.models import build_model
+    from repro.serve import ServeEngine
+    cfg = scale_down(ARCHS["qwen1.5-0.5b"], d_model=128, d_ff=256,
+                     head_dim=32, n_heads=4, n_kv_heads=4, vocab=256,
+                     n_layers=2)
+    api = build_model(cfg)
+    eng = ServeEngine(api, api.init_params(jax.random.key(0)), slots=2,
+                      s_max=32)
+    text = eng.lower_programs(2, 8)["step"].compile().as_text()
+    ops = {}
+    for line in text.splitlines():
+        if 'op_name="' not in line:
+            continue
+        path = line.split('op_name="', 1)[1].split('"', 1)[0].split("/")
+        for scope in ("attention", "kv_cache", "lm_head"):
+            if scope in path:
+                ops.setdefault(scope, []).append(path[-1])
+    assert set(ops) == {"attention", "kv_cache", "lm_head"}
+    assert "dot_general" in ops["attention"]
+    assert {"gather", "scatter"} <= set(ops["kv_cache"])
+    assert {"dot_general", "rsqrt"} <= set(ops["lm_head"])
+
+
+def test_launch_profile_dir_writes_serve_spans(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH",
+                                                             ""))
+    prof = tmp_path / "prof"
+    p = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--smoke",
+         "--requests", "2", "--max-new", "3", "--slots", "2", "--s-max",
+         "32", "--profile-dir", str(prof)],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    names = {e[0] for e in _serve_events(prof)}
+    assert {"serve.pump", "serve.admit", "serve.step"} <= names
 
 
 # --------------------------------------------------- backend/kernel hooks
