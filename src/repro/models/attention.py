@@ -180,14 +180,18 @@ def _decode_attend(q, k, v, kpos, pos, window, scale):
 
 
 @jax.named_scope("kv_cache")
-def _masked_row_scatter(cache, new, slot, active):
+def _masked_row_write(cache, new, slot, active):
     """cache:[B,W,...] <- new:[B,...] at per-row ``slot`` [B], only where
-    ``active`` [B]; inactive rows keep their cache bytes untouched."""
-    rows = jnp.arange(cache.shape[0])
-    keep = cache[rows, slot]
-    upd = active.reshape((-1,) + (1,) * (new.ndim - 1))
-    return cache.at[rows, slot].set(
-        jnp.where(upd, new.astype(cache.dtype), keep))
+    ``active`` [B]; inactive rows keep their cache bytes untouched.
+
+    An elementwise select, not a scatter: a select leaves the cache in
+    the device's default layout (a scatter's row window wants another,
+    and the compiler relayouts a scanned layer stack on the way in and
+    out of every step), and the attention reads it fused (DESIGN.md §6)."""
+    hit = (jnp.arange(cache.shape[1])[None] == slot[:, None]) \
+        & active[:, None]
+    return jnp.where(hit.reshape(hit.shape + (1,) * (new.ndim - 1)),
+                     new[:, None].astype(cache.dtype), cache)
 
 
 def _ring_gather(kv, plen, w):
@@ -283,8 +287,8 @@ def gqa_decode(p, x, cache, pos, cfg, window: int = 0, active=None):
     q, k, v = _qkv(p, x, cfg, pos[:, None])
     w = cache["k"].shape[1]
     slot = pos % w
-    kc = _masked_row_scatter(cache["k"], k[:, 0], slot, active)
-    vc = _masked_row_scatter(cache["v"], v[:, 0], slot, active)
+    kc = _masked_row_write(cache["k"], k[:, 0], slot, active)
+    vc = _masked_row_write(cache["v"], v[:, 0], slot, active)
     # per row, slot j holds position p = pos - ((pos - j) mod W)
     j = jnp.arange(w)
     kpos = pos[:, None] - ((pos[:, None] - j[None]) % w)
@@ -378,8 +382,8 @@ def mla_decode(p, x, cache, pos, cfg, active=None):
     ckv = linear(x, p["kv_down"])
     c_t, k_pe_raw = ckv[..., :cfg.kv_lora], ckv[..., cfg.kv_lora:]
     k_pe_t = apply_rope(k_pe_raw[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
-    cc = _masked_row_scatter(cache["c"], c_t[:, 0], pos, active)
-    pc = _masked_row_scatter(cache["k_pe"], k_pe_t[:, 0], pos, active)
+    cc = _masked_row_write(cache["c"], c_t[:, 0], pos, active)
+    pc = _masked_row_write(cache["k_pe"], k_pe_t[:, 0], pos, active)
     w_up = p["kv_up"]["w"].reshape(cfg.kv_lora, h, dn + dv)
     w_uk, w_uv = w_up[..., :dn], w_up[..., dn:]
     with jax.named_scope("attention"):

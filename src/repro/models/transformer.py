@@ -7,7 +7,8 @@ Compile-time discipline for the multi-pod dry-run:
   (one traced superblock regardless of depth);
 * the LM loss never materializes [B, S, V] logits — cross-entropy is
   computed in sequence chunks inside a scan;
-* decode carries all block caches through the same scan.
+* decode carries the stacked block caches in the scan's carry and
+  updates them in place, a layer at a time (DESIGN.md §6).
 
 The decode step names its head for the profiler (``jax.named_scope``,
 DESIGN.md §9): ``lm_head`` on the final norm and head projection.
@@ -246,7 +247,12 @@ def lm_decode_step(params, token, caches, pos, cfg, active=None):
     """token:[B,1] int32; pos:[B] i32 — each batch row's next position
     index (a scalar broadcasts); active:[B] bool — rows that decode this
     step and may write their cache region (None = all).  The scan body
-    carries the full vectors, so one jitted call serves a ragged batch."""
+    carries the full vectors, so one jitted call serves a ragged batch.
+
+    The stacked block caches ride in the scan's carry, not its xs/ys:
+    each layer reads its own layer of the one stacked buffer by index and
+    puts it back with its new rows written (DESIGN.md §6), so no layer's
+    cache is copied out of the stack or into a fresh one."""
     kinds = _slot_kinds(cfg)
     pos, active = norm_pos_active(pos, active, token.shape[0])
     x = _embed_tokens(params, cfg, {"tokens": token})
@@ -254,17 +260,25 @@ def lm_decode_step(params, token, caches, pos, cfg, active=None):
                                  caches=caches["first"], pos=pos,
                                  active=active)
 
-    def body(h, xs):
-        slot_params, slot_caches = xs
+    def body(carry, xs):
+        h, stack = carry
+        slot_params, layer = xs
+        with jax.named_scope("kv_cache"):
+            slot_caches = jax.tree.map(lambda l: l[layer], stack)
         new = {}
         for j, kind in enumerate(kinds):
-            h, c = block_decode(slot_params[f"slot{j}"], h,
-                                slot_caches[f"slot{j}"], pos, cfg, kind,
-                                cfg.moe_for_slot(j), active=active)
-            new[f"slot{j}"] = c
-        return h, new
+            h, new[f"slot{j}"] = block_decode(
+                slot_params[f"slot{j}"], h, slot_caches[f"slot{j}"], pos,
+                cfg, kind, cfg.moe_for_slot(j), active=active)
+        with jax.named_scope("kv_cache"):
+            stack = jax.tree.map(
+                lambda l, c: jax.lax.dynamic_update_index_in_dim(
+                    l, c, layer, 0), stack, new)
+        return (h, stack), None
 
-    x, block_caches = jax.lax.scan(body, x, (params["blocks"], caches["blocks"]))
+    (x, block_caches), _ = jax.lax.scan(
+        body, (x, caches["blocks"]),
+        (params["blocks"], jnp.arange(cfg.n_super)))
     with jax.named_scope("lm_head"):
         x = apply_norm(x, params["final_norm"], cfg.norm)
         logits = _head_logits(params, cfg, x[:, -1])

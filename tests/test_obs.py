@@ -318,8 +318,9 @@ def test_admit_span_carries_its_window(smoke_engine_parts, tmp_path):
 
 
 def test_step_program_holds_the_scopes():
-    # attention, the KV cache's ring-slot writes and the head each carry
-    # their named scope in the compiled step program's metadata
+    # attention, the KV cache's row writes into the carried layer stack
+    # and its layer reads, and the head each carry their named scope in
+    # the compiled step program's metadata
     from repro.configs import ARCHS, scale_down
     from repro.models import build_model
     from repro.serve import ServeEngine
@@ -340,7 +341,8 @@ def test_step_program_holds_the_scopes():
                 ops.setdefault(scope, []).append(path[-1])
     assert set(ops) == {"attention", "kv_cache", "lm_head"}
     assert "dot_general" in ops["attention"]
-    assert {"gather", "scatter"} <= set(ops["kv_cache"])
+    assert {"select_n", "dynamic_slice", "dynamic_update_slice"} \
+        <= set(ops["kv_cache"])
     assert {"dot_general", "rsqrt"} <= set(ops["lm_head"])
 
 

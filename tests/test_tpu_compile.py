@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the SME Pallas kernels for a TPU v5e.
+"""Ahead-of-time compiles for a TPU v5e: the SME Pallas kernels, and the
+serving engine's decode step.
 
 The TPU compiler is installed with JAX, so each kernel is compiled here
 for a described (not attached) ``v5e:2x2`` topology, at the projection
@@ -13,12 +14,18 @@ The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every test
 worker imports every test file.
 """
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core import backend as B
+from repro.models.model import build_model
 
 #: (K, N) of qwen1.5-0.5b's projections: fused qkv, o, MLP in, MLP out
 SHAPES = [(1024, 3072), (1024, 1024), (1024, 2816), (2816, 1024)]
@@ -94,3 +101,40 @@ def test_kernel_compiles_for_v5e(one_chip, backend, m, k, n):
     if backend == "v3":
         # M picks the path: the decode kernel has its own grid
         assert B._use_decode_kernel(m, 128) == (m == 8)
+
+
+def test_decode_step_moves_no_cache_layer(one_chip):
+    """The engine's step program (``decode_chunk``, one position per row)
+    at qwen1.5-0.5b widths with 2 layers, 16 slots and ``s_max`` 1024,
+    its cache donated in the device's default layout: the compiled
+    program holds no ``copy`` as large as one layer's K.  A layer loop
+    that slices each layer's cache out and stacks a new one, or a row
+    write that wants the cache in another layout than the program's
+    boundary has, copies whole layers every step."""
+    cfg = dataclasses.replace(get_config("qwen1.5-0.5b"), n_layers=2)
+    api = build_model(cfg)
+    slots, s_max, i32 = 16, 1024, jnp.int32
+
+    def spec(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def step(p, tokens, caches, pos, nvalid, active):
+        logits, live, caches = api.decode_chunk(p, tokens, caches, pos,
+                                                nvalid, active)
+        return jnp.argmax(logits, axis=-1), live, caches
+
+    row = jax.ShapeDtypeStruct((slots,), i32)
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        jax.tree.map(spec, api.abstract_params()),
+        spec(jax.ShapeDtypeStruct((slots, 1), i32)),
+        jax.tree.map(spec, api.abstract_cache(batch=slots, s_max=s_max)),
+        spec(row), spec(row),
+        spec(jax.ShapeDtypeStruct((slots,), jnp.bool_))).compile()
+
+    layer_k = slots * s_max * cfg.n_kv_heads * cfg.hd
+    copies = re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(",
+                        compiled.as_text())
+    big = [dims for dims in copies
+           if math.prod(int(d) for d in dims.split(",") if d) >= layer_k]
+    assert not big, f"copies of a cache layer or more: {big}"
