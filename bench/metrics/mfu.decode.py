@@ -1,6 +1,6 @@
 """The whole step's share of the chip's peak: operations of every token
 processed in the traced window (prompt tokens at admission, each fed-back
-output token at its decode step, the tied head wherever logits are due,
+output token at its decode step, the head wherever logits are due,
 attention at the live context length), over window times peak."""
 import work
 

@@ -1,8 +1,8 @@
 """The SME kernels' share of their roofline in decode steps: the least
 time the chip needs for the projections of the traced ``bench.step``
-spans (every layer's q, k, v, o, gate, up and down on all ``slots``
-rows; ``work.sme_call``), over the summed device time of the SME kernel
-events inside those spans.
+spans (every SME call of one decode step on all ``slots`` rows, as the
+architecture's module counts them: ``sme_calls``), over the summed
+device time of the SME kernel events inside those spans.
 
 An SME kernel is a Pallas call (custom-call target ``tpu_custom_call``)
 whose HLO name the program's backend wrappers give it: ``_v1_call``,
@@ -10,6 +10,7 @@ whose HLO name the program's backend wrappers give it: ``_v1_call``,
 import re
 
 import work
+from arch import for_config
 
 _SME_CALL = re.compile(r"_v\d\w*_call(\.\d+)*$")
 
@@ -26,8 +27,6 @@ def read(ctx):
     if not ops:
         return None
     cfg, m = ctx.cell.config, ctx.cell.traffic["slots"]
-    per_step = work.least_time(
-        [work.sme_call(m, k, n) for k, n in work.projections(cfg)]
-        * cfg["num_hidden_layers"], ctx.peaks)
+    per_step = work.least_time(for_config(cfg).sme_calls(cfg, m), ctx.peaks)
     kernel_s = sum(op[2] for op in ops) / 1e9 / len(ctx.trace.devices)
     return 100.0 * per_step * len(steps) / kernel_s

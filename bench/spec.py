@@ -4,9 +4,10 @@ A cell is one entry of ``workloads``: it names a configuration (whose
 ``file`` holds the sizes) and a traffic mix (``traffic/<name>.json``).
 Its metrics are the ``end_to_end`` and ``per_layer`` entries whose
 ``workloads`` list names the cell, or that have no such list.  A
-per-layer metric is read by ``metrics/<name>.py``.  Adding a
-configuration, a mix or a metric is adding files and entries: nothing
-here names one.
+per-layer metric is read by ``metrics/<name>.py``, and what belongs to
+the configuration's architecture by ``arch/<architectures[0]>.py``.
+Adding a configuration (of a new architecture too), a mix or a metric
+is adding files and entries: nothing here names one.
 """
 from __future__ import annotations
 

@@ -3,9 +3,9 @@ the CPU holds: the float8 control put in the program's place in the
 check, and the timed path broken underneath a whole run (the device
 check skipped).
 
-Tiny readings (CPU, seeds 1-6, ``tiny.tiny_cell``): sound runs read a
-widest logit gap of 0 to 7.1e-4, the float8 control 1.0e-2 to 3.0e-2;
-the tiny limit sits between them."""
+Tiny readings (CPU, seeds 1-12, ``tiny.tiny_cell``): sound runs read a
+mean logit gap of 0 to 9.7e-7, the float8 control 3.8e-5 to 1.8e-4; the
+tiny limit sits between them."""
 import time
 
 import jax
@@ -26,11 +26,11 @@ def _run(seed, hook=None, control=None):
 @pytest.mark.parametrize("seed", [1, 3, 5])
 def test_control_fails_and_program_passes(seed):
     out = _run(seed, control=CONTROL)
-    limit = out["checks"]["logit_gap"]["limit"]
+    limit = out["checks"]["logit_gap_mean"]["limit"]
     assert not out["correct"], out["checks"]
-    assert out["checks"]["logit_gap"]["value"] == \
-        out["readings"]["control"]["max"] > limit
-    assert out["readings"]["program"]["max"] <= limit
+    assert out["checks"]["logit_gap_mean"]["value"] == \
+        out["readings"]["control"]["mean"] > limit
+    assert out["readings"]["program"]["mean"] <= limit
 
 
 def _alter_token(eng):

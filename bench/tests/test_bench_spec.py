@@ -77,3 +77,35 @@ def test_dropped_in_files_are_found(tmp_path):
     assert metric_reader("new_metric.x", bench).read(None) == 42.0
     with pytest.raises(SystemExit):
         load_cell("no-such-cell", root=tmp_path)
+
+
+def test_unknown_architecture_names_the_file_to_add():
+    from arch import for_config
+    cfg = dict(read_json(BENCH / "configs" / "qwen1.5-0.5b.sme-v2.json"),
+               architectures=["DeepseekV2ForCausalLM"])
+    with pytest.raises(ValueError, match=re.escape(
+            f"add {BENCH.name}/arch/DeepseekV2ForCausalLM.py")):
+        for_config(cfg)
+    with pytest.raises(ValueError, match="arch/"):
+        for_config(dict(cfg, architectures=["../spec"]))
+
+
+#: where an architecture may be named: its module, its reference, its
+#: configuration files (and the tests)
+ARCH_DIRS = ("arch", "reference", "configs", "tests")
+QWEN_NAMES = re.compile(r"qwen|\b(?:[qkvo]_[wb]|gate_w|up_w|down_w)\b",
+                        re.IGNORECASE)
+
+
+def test_only_architecture_files_name_one():
+    """A configuration of another architecture needs only new files: no
+    other file of the benchmark knows the names of one."""
+    found = [f"{path.relative_to(BENCH)}:{n}"
+             for path in sorted(BENCH.rglob("*")) if path.is_file()
+             and path.relative_to(BENCH).parts[0] not in ARCH_DIRS
+             and not path.relative_to(BENCH).parts[0].startswith(".")
+             and path.suffix in (".py", ".json", ".jsonl", ".toml", ".txt",
+                                 ".csv")
+             for n, line in enumerate(path.read_text().splitlines(), 1)
+             if QWEN_NAMES.search(line)]
+    assert not found, found

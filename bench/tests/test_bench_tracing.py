@@ -8,6 +8,7 @@ import pytest
 import tiny
 import tracing
 import work
+from arch import for_config
 from harness import Ctx
 from peaks import PEAKS
 from spec import load_cell, metric_reader
@@ -59,7 +60,8 @@ def test_hand_trace_readers():
         pytest.approx(100e-9 * 1e3)
     cfg, slots = ctx.cell.config, ctx.cell.traffic["slots"]
     least = work.least_time(
-        [work.sme_call(slots, k, n) for k, n in work.projections(cfg)]
+        [work.sme_call(slots, k, n)
+         for k, n in for_config(cfg).projections(cfg)]
         * cfg["num_hidden_layers"], V5E)
     assert metric_reader("sme_roofline.decode").read(ctx) == \
         pytest.approx(100.0 * 2 * least / 100e-9)

@@ -1,12 +1,15 @@
-"""Operations and bytes from shapes, against counts made by hand."""
+"""Operations and bytes from shapes, against counts made by hand: the
+generic ones of ``work`` and the Qwen2 architecture module's."""
 import pytest
 
 import tiny  # noqa: F401  (puts bench/ on the path)
 import work
+from arch import for_config
 from peaks import PEAKS, peaks
 from spec import BENCH, read_json
 
 QWEN15 = read_json(BENCH / "configs" / "qwen1.5-0.5b.sme-v2.json")
+QWEN2 = for_config(QWEN15)
 V5E = PEAKS["TPU v5 lite"]
 
 
@@ -23,29 +26,39 @@ def test_one_projection_by_hand():
 
 
 def test_projections_of_a_layer():
-    assert work.projections(QWEN15) == [
+    assert QWEN2.__name__ == "arch.Qwen2ForCausalLM"
+    assert QWEN2.projections(QWEN15) == [
         (1024, 1024), (1024, 1024), (1024, 1024), (1024, 1024),
         (1024, 2816), (1024, 2816), (2816, 1024)]
     # qwen2-0.5b's widths, GQA: 2 KV heads of 64 -> k and v project 896 -> 128
     q2 = dict(QWEN15, hidden_size=896, num_attention_heads=14,
               num_key_value_heads=2, intermediate_size=4864)
-    assert work.projections(q2)[1:3] == [(896, 128), (896, 128)]
+    assert QWEN2.projections(q2)[1:3] == [(896, 128), (896, 128)]
+
+
+def test_sme_calls_of_a_decode_step():
+    # every layer's seven projections on the 16 decode rows, layer by layer
+    calls = QWEN2.sme_calls(QWEN15, 16)
+    assert len(calls) == 24 * 7
+    assert calls[4] == calls[7 * 23 + 4] == work.sme_call(16, 1024, 2816)
+    assert calls[:7] == [work.sme_call(16, k, n)
+                         for k, n in QWEN2.projections(QWEN15)]
 
 
 def test_token_flops_by_hand():
     per_layer = 2 * (4 * 1024 * 1024 + 3 * 1024 * 2816)
     attn = 4 * 16 * 64 * 10             # scores and values over 10 positions
     head = 2 * 1024 * 151936
-    assert work.token_flops(QWEN15, 9, False) == 24 * (per_layer + attn)
-    assert work.token_flops(QWEN15, 9, True) == \
+    assert QWEN2.token_flops(QWEN15, 9, False) == 24 * (per_layer + attn)
+    assert QWEN2.token_flops(QWEN15, 9, True) == \
         24 * (per_layer + attn) + head
 
 
 def test_request_flops_counts_each_token_once():
     full = work.request_flops(QWEN15, 5, 3, True, [1, 2])
-    prefill = sum(work.token_flops(QWEN15, p, p == 4) for p in range(5))
-    assert full == prefill + work.token_flops(QWEN15, 5, True) + \
-        work.token_flops(QWEN15, 6, True)
+    prefill = sum(QWEN2.token_flops(QWEN15, p, p == 4) for p in range(5))
+    assert full == prefill + QWEN2.token_flops(QWEN15, 5, True) + \
+        QWEN2.token_flops(QWEN15, 6, True)
     assert work.request_flops(QWEN15, 5, 3, False, []) == 0
 
 
