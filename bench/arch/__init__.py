@@ -16,12 +16,23 @@ A module provides:
   one decode step on ``rows`` rows (``work.sme_call``);
 * ``token_flops(cfg, pos, head)``: operations to process one token at
   position ``pos``, with the head when ``head``.
+
+and may declare, where its program does more than every decoder's:
+
+* ``SCOPES``: the ``jax.named_scope`` names its program sets beyond
+  ``program_trace.SCOPES`` (``("moe",)``), which the per-layer readers
+  then read (``scopes``, ``program_trace.Program.scope_ms_per_step``);
+* ``KINDS``: ``{kind: fn(z, shape) -> z}``, draws of its own for its
+  ``leaf_specs``, each from a standard normal ``z`` of ``shape`` in
+  float32, beside the generic kinds of ``weights.DRAWS``.
+
+A module is found on this package's path (``__path__``).
 """
 from __future__ import annotations
 
 import importlib
 import pathlib
-from typing import Dict
+from typing import Dict, Tuple
 
 ARCH = pathlib.Path(__file__).resolve().parent
 
@@ -30,8 +41,24 @@ def for_config(cfg: Dict):
     """The module of ``cfg``'s architecture; a name without one is an
     error that names the file to add."""
     name = cfg["architectures"][0]
-    if not name.isidentifier() or not (ARCH / f"{name}.py").is_file():
+    if not name.isidentifier() or not any(
+            (pathlib.Path(p) / f"{name}.py").is_file() for p in __path__):
         raise ValueError(f"no benchmark module for the architecture "
                          f"{name!r}: add {ARCH.parent.name}/{ARCH.name}/"
                          f"{name}.py")
     return importlib.import_module(f"{__name__}.{name}")
+
+
+def scopes(cfg: Dict) -> Tuple[str, ...]:
+    """The scopes a trace of ``cfg``'s program is read by: every
+    decoder's (``program_trace.SCOPES``), then those its architecture's
+    module declares in ``SCOPES``.  A declared name that is one of
+    those, or ``unscoped``, is an error."""
+    from program_trace import SCOPES, UNSCOPED
+    mod = for_config(cfg)
+    own = tuple(getattr(mod, "SCOPES", ()))
+    clash = sorted(set(own) & set(SCOPES + (UNSCOPED,)))
+    if clash:
+        raise ValueError(f"{mod.__name__}.SCOPES names {clash}, which "
+                         f"every trace already reads")
+    return SCOPES + own

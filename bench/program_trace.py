@@ -3,18 +3,24 @@
 ``extract`` is ``tracing.extract`` with two keys added and nothing else
 changed:
 
-    {"devices": [{"plane", "modules", "ops", "scopes": [scope, ...]}],
+    {"devices": [{"plane", "modules", "ops", "paths": [op_path, ...]}],
      "host": [...],
      "program": [[name, start_ns, dur_ns, {arg: value}], ...]}
 
 ``program`` holds the engine's host spans (``serve.*``
 ``TraceAnnotation``s, ``serve/engine.py``) with their arguments.
-``scopes`` runs beside ``ops``: for each op, the innermost of ``SCOPES``
-its ``op_name`` metadata names, or None (``unscoped``: never guessed
-into a layer).  A TPU trace keeps that metadata on each op's event
-metadata (the ``tf_op`` stat), which ``ProfileData`` does not expose, so
-it is read from the serialized XSpace with a protobuf wire decoder and
-matched to the op events by program id and HLO text.
+``paths`` runs beside ``ops``: for each op, its ``op_name`` metadata
+(``jit(chunk_fn)/while/body/attention/dot_general``), or None.  A TPU
+trace keeps that metadata on each op's event metadata (the ``tf_op``
+stat), which ``ProfileData`` does not expose, so it is read from the
+serialized XSpace with a protobuf wire decoder and matched to the op
+events by program id and HLO text.
+
+An op's scope is resolved when it is read, against the cell's set of
+scopes (``arch.scopes``: ``SCOPES`` and those the architecture's module
+declares): the innermost of the set its path names, or None
+(``unscoped``: never guessed into a layer).  A recorded trace may hold
+``scopes`` in place of ``paths``, already resolved against ``SCOPES``.
 
 A program older than the spans and scopes reads as no spans and no
 scopes, and every reader of them then returns None.  Readers reach the
@@ -30,12 +36,14 @@ import bisect
 import glob
 import os
 import re
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+import arch
 import tracing
 
 PREFIX = "serve."
-#: the named scopes of the step program (``jax.named_scope``)
+#: the named scopes every architecture's step program sets
+#: (``jax.named_scope``); an architecture's module may declare more
 SCOPES = ("attention", "kv_cache", "lm_head")
 UNSCOPED = "unscoped"
 #: the engine's step program, as the trace names its runs
@@ -43,10 +51,10 @@ STEP_PROGRAM = "jit_chunk_fn"
 _PROGRAM_ID = re.compile(r"\((\d+)\)$")
 
 
-def scope_of(op_path: str) -> Optional[str]:
-    """The innermost of ``SCOPES`` among the components of an ``a/b/c``
+def scope_of(op_path: str, scopes: Sequence[str] = SCOPES) -> Optional[str]:
+    """The innermost of ``scopes`` among the components of an ``a/b/c``
     op path, or None."""
-    found = [p for p in op_path.split("/") if p in SCOPES]
+    found = [p for p in op_path.split("/") if p in scopes]
     return found[-1] if found else None
 
 
@@ -90,9 +98,10 @@ def _map_values(entries) -> Iterator:
                 yield v
 
 
-def op_scopes(xspace: bytes) -> Dict[str, Dict[Tuple[int, str], str]]:
-    """Per device plane, ``{(program id, op event name): scope}`` for
-    every op whose ``tf_op`` stat names a scope.  XSpace: planes = 1;
+def op_paths(xspace: bytes) -> Dict[str, Dict[Tuple[int, str], str]]:
+    """Per device plane, ``{(program id, op event name): op path}`` for
+    every op with a ``tf_op`` stat: one entry per op of a program, not
+    per event.  XSpace: planes = 1;
     XPlane: name = 2, event_metadata = 4, stat_metadata = 5;
     XEventMetadata: name = 2, stats = 5; XStat: metadata_id = 1,
     uint64 = 3, int64 = 4, str = 5; XStatMetadata: id = 1, name = 2."""
@@ -128,20 +137,19 @@ def op_scopes(xspace: bytes) -> Dict[str, Dict[Tuple[int, str], str]]:
                         op_path = bytes(stat.get(5, b"")).decode()
                     elif stat.get(1, 0) == ids.get("program_id"):
                         program = stat.get(3, stat.get(4))
-            scope = scope_of(op_path)
-            if scope is not None:
-                found[(program, op_name)] = scope
+            if op_path:
+                found[(program, op_name)] = op_path
     return out
 
 
 def extract(xspace: bytes) -> Dict:
     """``tracing.extract`` of the serialized XSpace ``xspace``, plus
-    ``program`` and each device's ``scopes``."""
+    ``program`` and each device's ``paths``."""
     import jax
     pd = jax.profiler.ProfileData.from_serialized_xspace(xspace)
     data = tracing.extract(pd)
-    by_plane = op_scopes(xspace)
-    program, scopes = [], []
+    by_plane = op_paths(xspace)
+    program, paths = [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
             found = by_plane.get(plane.name, {})
@@ -159,9 +167,9 @@ def extract(xspace: bytes) -> Dict:
             if runs:
                 run = tracing.enclosing(sorted(runs))
                 # ``tracing.extract`` sorts the same events the same way
-                scopes.append([found.get((run(s), name))
-                               for s, name in sorted(ops,
-                                                     key=lambda o: o[0])])
+                paths.append([found.get((run(s), name))
+                              for s, name in sorted(ops,
+                                                    key=lambda o: o[0])])
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
@@ -170,8 +178,8 @@ def extract(xspace: bytes) -> Dict:
                                 if not k.startswith("_")}
                         program.append([ev.name, int(ev.start_ns),
                                         int(ev.duration_ns), args])
-    for dev, found in zip(data["devices"], scopes):
-        dev["scopes"] = found
+    for dev, found in zip(data["devices"], paths):
+        dev["paths"] = found
     program.sort(key=lambda p: p[1])
     data["program"] = program
     return data
@@ -189,8 +197,9 @@ def read_dir(trace_dir: str) -> Dict:
 class Program:
     """The program's spans and scoped device time in one trace."""
 
-    def __init__(self, trace: tracing.Trace):
+    def __init__(self, trace: tracing.Trace, scopes: Sequence[str] = SCOPES):
         self.trace = trace
+        self.scopes = tuple(scopes)
         lo, hi = trace.window()
         self.spans_ = [p for p in trace.data["program"] if lo <= p[1] < hi]
 
@@ -220,17 +229,27 @@ class Program:
                 skew = max(skew, s - min(near, key=lambda t: abs(t - s)))
         return skew
 
+    def op_scopes(self, dev: Dict) -> List[Optional[str]]:
+        """Each op's scope among ``self.scopes``, or None: resolved from
+        ``dev``'s ``paths``, or as a recorded trace holds it resolved."""
+        paths = dev.get("paths")
+        if paths is None:
+            return dev.get("scopes") or [None] * len(dev["ops"])
+        of = {p: scope_of(p, self.scopes) for p in set(paths) if p}
+        return [of.get(p) for p in paths]
+
     def scoped_ns(self, spans: List[Tuple[int, int]]) -> Dict[str, int]:
         """Device ns of the ops (containers and async ends left out, as in
-        ``Trace.top_ops``) that start inside host ``spans``, by scope,
-        summed over devices; ``unscoped`` holds the ops no scope names.
-        Op stamps are moved onto the host clock by ``skew_ns`` first."""
+        ``Trace.top_ops``) that start inside host ``spans``, by scope of
+        ``self.scopes``, summed over devices; ``unscoped`` holds the ops
+        no scope names.  Op stamps are moved onto the host clock by
+        ``skew_ns`` first."""
         inside = tracing.enclosing(sorted((s, e, "") for s, e in spans))
-        tot = {s: 0 for s in SCOPES + (UNSCOPED,)}
+        tot = {s: 0 for s in self.scopes + (UNSCOPED,)}
         for dev in self.trace.devices:
             skew = self.skew_ns(dev)
-            scopes = dev.get("scopes") or [None] * len(dev["ops"])
-            for (name, s, dur, _), scope in zip(dev["ops"], scopes):
+            for (name, s, dur, _), scope in zip(dev["ops"],
+                                                 self.op_scopes(dev)):
                 cls = tracing.op_class(name)
                 if inside(s + skew) is None or cls in tracing.CONTAINERS \
                         or tracing._ASYNC.search(cls):
@@ -240,9 +259,13 @@ class Program:
 
     def scope_ms_per_step(self, scope: str) -> Optional[float]:
         """Device ms per ``bench.step`` of the ops scoped ``scope``; None
-        when the trace names no scope at all (a program without them)."""
-        if not any(any(dev.get("scopes") or ()) for dev in
-                   self.trace.devices):
+        when the trace names no scope at all (a program without them).
+        A scope the cell's architecture does not declare is an error."""
+        if scope not in self.scopes:
+            raise ValueError(f"scope {scope!r} is not among {self.scopes}: "
+                             f"the architecture's module declares its own "
+                             f"in SCOPES")
+        if not any(any(self.op_scopes(dev)) for dev in self.trace.devices):
             return None
         steps = self.trace.spans(tracing.HOST_PREFIX + "step")
         if not steps:
@@ -258,9 +281,10 @@ def trace_dir(cell_name: str) -> str:
 
 
 def program(ctx) -> Optional[Program]:
-    """The run's ``Program``, or None when the run was not traced.  A
-    program older than the spans and scopes gives one with no spans and
-    no scopes.  A traced run whose xplane is not where ``trace_dir``
+    """The run's ``Program``, reading the scopes of the cell's
+    architecture (``arch.scopes``), or None when the run was not traced.
+    A program older than the spans and scopes gives one with no spans
+    and no scopes.  A traced run whose xplane is not where ``trace_dir``
     says raises ``FileNotFoundError``: a moved trace directory must not
     read as a program without spans."""
     trace = getattr(ctx, "trace", None)
@@ -270,5 +294,5 @@ def program(ctx) -> Optional[Program]:
         extra = read_dir(trace_dir(ctx.cell.name))
         trace.data["program"] = extra["program"]
         for dev, more in zip(trace.devices, extra["devices"]):
-            dev.setdefault("scopes", more.get("scopes"))
-    return Program(trace)
+            dev.setdefault("paths", more.get("paths"))
+    return Program(trace, arch.scopes(ctx.cell.config))

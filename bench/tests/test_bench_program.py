@@ -1,7 +1,8 @@
 """The program's spans and scopes read from a profiler trace: the six
 readers on a trace made by hand, on a program without spans or scopes,
-and on a slice of a trace recorded on a TPU v5e; the readings the
-benchmark had before them left as they were."""
+and on a slice of a trace recorded on a TPU v5e; a scope that an
+architecture's module declares; the readings the benchmark had before
+them left as they were."""
 import copy
 import json
 import pathlib
@@ -15,7 +16,7 @@ import tracing
 import program_trace
 from harness import Ctx
 from peaks import PEAKS
-from spec import load_cell, metric_reader
+from spec import BENCH, REPO, load_cell, metric_reader, read_json
 
 FIXTURES = pathlib.Path(__file__).parent / "fixtures"
 V5E = PEAKS["TPU v5 lite"]
@@ -58,6 +59,21 @@ HAND_MS = {"step_host_ms.decode": (80 + 40) / 2 / 1e6,
            "lm_head_ms.decode": 40 / 2 / 1e6}
 
 
+#: ``HAND`` with each op's path in place of its resolved scope: the SME
+#: kernel under a ``moe`` scope, the second step's copy under ``moe``
+#: inside ``attention``
+HAND_PATHS = copy.deepcopy(HAND)
+del HAND_PATHS["devices"][0]["scopes"]
+HAND_PATHS["devices"][0]["paths"] = [
+    "jit(chunk_fn)/while:",
+    "jit(chunk_fn)/while/body/attention/dot_general:",
+    "jit(chunk_fn)/while/body/moe/jit(_v2_call)/pallas_call:",
+    None,
+    "jit(chunk_fn)/while/body/attention/moe/copy:",
+    "jit(chunk_fn)/lm_head/argmax:"]
+STUB = "StubMoeForCausalLM"
+
+
 def _ctx(data, cell=None):
     return Ctx(cell or tiny.tiny_cell(), V5E, None, tracing.Trace(data))
 
@@ -92,6 +108,65 @@ def test_device_stamps_ahead_of_the_host_are_moved_back():
                  "lm_head_ms.decode"):
         assert metric_reader(name).read(_ctx(copy.deepcopy(early))) == \
             pytest.approx(HAND_MS[name])
+
+
+def test_an_architecture_brings_its_own_scope(tmp_path):
+    """A scope the program sets for one architecture is read with only
+    new files: the architecture's module declaring it, a configuration,
+    a mix, readers and a ``BENCHMARK.json`` entry.  It is read per step,
+    the innermost scope of the cell's set holds an op, and the scopes and
+    ``unscoped`` add up to the ops; a new cell's reader may delegate to
+    an existing one."""
+    bench = tmp_path / BENCH.name
+    for sub in ("configs", "traffic", "metrics"):
+        (bench / sub).mkdir(parents=True)
+    cfg = dict(tiny.tiny_cell().config, architectures=[STUB])
+    (bench / "configs" / "stub.json").write_text(json.dumps(cfg))
+    (bench / "traffic" / "closed-decode.json").write_text(
+        (BENCH / "traffic" / "closed-decode.json").read_text())
+    (bench / "metrics" / "moe_ms.stub.py").write_text(
+        "import program_trace\n\n\ndef read(ctx):\n"
+        "    prog = program_trace.program(ctx)\n"
+        "    return prog.scope_ms_per_step('moe') if prog else None\n")
+    (bench / "metrics" / "attn_ms.stub.py").write_text(
+        "from spec import metric_reader\n\n"
+        "read = metric_reader('attn_ms.decode').read\n")
+    spec = read_json(REPO / "BENCHMARK.json")
+    layer = [{"name": n, "unit": "ms", "better": "lower",
+              "source": "device_trace", "layer": n.split("_")[0],
+              "moves": "output_tok_s", "workloads": ["stub-decode"]}
+             for n in ("moe_ms.stub", "attn_ms.stub")]
+    spec.update(
+        configs=[{"name": "stub", "source": "x", "reduced": [], "why": "x",
+                  "file": f"{BENCH.name}/configs/stub.json"}],
+        workloads=[{"name": "stub-decode", "config": "stub", "chips": 1,
+                    "traffic": "closed-decode", "why": "x"}],
+        per_layer=layer)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    cell = load_cell("stub-decode", root=tmp_path)
+    with tiny.arch_module(bench / "arch", STUB, 'SCOPES = ("moe",)\n'):
+        ctx = _ctx(copy.deepcopy(HAND_PATHS), cell)
+        values = {m["name"]: metric_reader(m["name"], bench).read(ctx)
+                  for m in cell.per_layer}
+        prog = program_trace.program(ctx)
+        by = prog.scoped_ns(prog.trace.spans("bench.step"))
+    assert values == pytest.approx({"moe_ms.stub": (100 + 50) / 2 / 1e6,
+                                    "attn_ms.stub": 100 / 2 / 1e6})
+    assert by == {"attention": 100, "kv_cache": 0, "lm_head": 40,
+                  "moe": 150, "unscoped": 0}
+
+
+def test_a_scope_the_architecture_does_not_declare_is_unscoped():
+    """Under Qwen2's set the same trace reads the ``moe`` kernel as
+    ``unscoped`` and the nested copy as ``attention``, with the same
+    total; a reader of ``moe`` there is an error, not a reading."""
+    prog = program_trace.program(_ctx(copy.deepcopy(HAND_PATHS)))
+    by = prog.scoped_ns(prog.trace.spans("bench.step"))
+    assert by == {"attention": 150, "kv_cache": 0, "lm_head": 40,
+                  "unscoped": 100}
+    assert sum(by.values()) == 100 + 100 + 50 + 40
+    with pytest.raises(ValueError, match="SCOPES"):
+        prog.scope_ms_per_step("moe")
 
 
 def _bare_capture(trace_dir) -> None:
@@ -196,7 +271,12 @@ def test_extract_reads_scopes_from_the_op_metadata():
     [dev] = data["devices"]
     assert [(o[0], o[1]) for o in dev["ops"]] == [
         ("fusion.1", 1_010), ("_v2_call.3", 1_060), ("fusion.1", 1_210)]
-    assert dev["scopes"] == ["attention", None, "kv_cache"]
+    assert dev["paths"] == [
+        "jit(chunk_fn)/while/body/attention/dot_general:",
+        "jit(chunk_fn)/jit(_v2_call)/pallas_call:",
+        "jit(chunk_fn)/while/body/kv_cache/copy:"]
+    assert [program_trace.scope_of(p) for p in dev["paths"]] == [
+        "attention", None, "kv_cache"]
     assert [m[0] for m in dev["modules"]] == ["jit_chunk_fn"] * 2
 
 
@@ -233,7 +313,7 @@ def test_extract_adds_to_what_tracing_extract_keeps(tmp_path):
     [path] = tmp_path.glob("**/*.xplane.pb")
     data = program_trace.read_dir(str(tmp_path))
     for dev in data["devices"]:
-        del dev["scopes"]
+        del dev["paths"]
     assert {k: data[k] for k in ("devices", "host")} == tracing.extract(
         jax.profiler.ProfileData.from_file(str(path)))
     [(name, _, dur, args)] = data["program"]

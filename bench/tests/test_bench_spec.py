@@ -1,11 +1,15 @@
 """The harness finds every piece of a cell by the names in BENCHMARK.json,
 and a piece dropped in as a new file is found without an edit."""
+import functools
 import json
 import re
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
-import tiny  # noqa: F401  (puts bench/ on the path)
+import tiny
 from spec import BENCH, REPO, load_cell, metric_reader, read_json
 
 SPEC = read_json(REPO / "BENCHMARK.json")
@@ -109,3 +113,56 @@ def test_only_architecture_files_name_one():
              for n, line in enumerate(path.read_text().splitlines(), 1)
              if QWEN_NAMES.search(line)]
     assert not found, found
+
+
+#: an architecture with a draw of its own, as a state-space layer needs
+#: for its ``D`` (ones) and the like; fan-in 16 makes "proj" exact
+DRAW_ARCH = """
+import jax.numpy as jnp
+
+KINDS = {"skip": lambda z, shape: jnp.ones(shape, z.dtype),
+         "wide": lambda z, shape: 2.0 * z}
+
+
+def leaf_specs(cfg):
+    return {"D": ((4, 8), "skip"), "w": ((16, 8), "proj"),
+            "x": ((8, 8), "wide")}
+"""
+
+
+def test_an_architecture_brings_its_own_draw(tmp_path):
+    """A draw kind declared by the architecture's module alone is drawn,
+    beside the generic kinds, from the leaf's own key (leaf ``i`` of the
+    sorted names)."""
+    import weights
+    name = "StubDrawForCausalLM"
+    with tiny.arch_module(tmp_path, name, DRAW_ARCH):
+        w = weights.make_weights({"architectures": [name]}, 2**31 + 5)
+
+    @functools.partial(jax.jit, static_argnums=(0, 1))
+    def z(i, shape):
+        return jax.random.normal(jax.random.fold_in(
+            weights.seed_key(2**31 + 5), i), shape, jnp.float32)
+    want = {"D": np.ones((4, 8), np.float32),
+            "w": z(1, (16, 8)) * 0.25, "x": 2.0 * z(2, (8, 8))}
+    for k, v in want.items():
+        assert w[k].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(w[k]),
+                                      np.asarray(jnp.asarray(v, jnp.bfloat16)))
+
+
+@pytest.mark.parametrize("source", [
+    "KINDS = {'proj': lambda z, shape: z}\n",
+    "SCOPES = ('attention',)\n",
+    "SCOPES = ('unscoped',)\n"])
+def test_an_architecture_may_not_redefine_a_generic_name(tmp_path, source):
+    """A draw kind or a scope that every architecture has already is not
+    the module's to declare."""
+    import weights
+    from arch import scopes
+    name = "StubClashForCausalLM"
+    cfg = {"architectures": [name]}
+    read = scopes if source.startswith("SCOPES") else weights.draws
+    with tiny.arch_module(tmp_path, name, source):
+        with pytest.raises(ValueError, match=name):
+            read(cfg)

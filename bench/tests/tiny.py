@@ -2,8 +2,10 @@
 configuration and the closed-loop traffic mix with every size cut, under
 every metric of ``BENCHMARK.json``.  Widths stay multiples of
 the 128-wide SME tile, so every projection is packed and served through
-the SME path."""
+the SME path.  ``arch_module`` adds an architecture's module as a file."""
+import contextlib
 import os
+import pathlib
 import sys
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -34,3 +36,22 @@ def tiny_cell(traffic: str = "closed-decode",
     tr["output_tokens"] = dict(median=20, sigma=0.5, min=10, max=40)
     return Cell(f"tiny-{traffic}", "tiny", cfg, traffic, tr, 1,
                 spec["end_to_end"], spec["per_layer"])
+
+
+@contextlib.contextmanager
+def arch_module(directory, name: str, source: str):
+    """The module ``<directory>/<name>.py`` of ``source``, which
+    ``arch.for_config`` finds for the architecture ``name`` while the
+    block runs, as it finds a file added under ``bench/arch/``."""
+    import arch
+    directory = pathlib.Path(directory)
+    directory.mkdir(parents=True, exist_ok=True)
+    (directory / f"{name}.py").write_text(source)
+    saved = list(arch.__path__)
+    arch.__path__.append(str(directory))
+    try:
+        yield
+    finally:
+        arch.__path__[:] = saved
+        sys.modules.pop(f"arch.{name}", None)
+        vars(arch).pop(name, None)
